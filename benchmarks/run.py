@@ -511,8 +511,8 @@ def bench_fleet(out_path="BENCH_fleet.json", scenario_names=None):
             "parity": ok,
         })
     # compiled fleet pipeline (ISSUE 8): the WHOLE window pipeline (gate
-    # -> device FIFO queues -> uplink -> shared cloud) as ONE jitted
-    # program, max-plus associative_scan recurrences, shard_map over the
+    # -> device FIFO queues -> uplink -> shared cloud) as jitted device
+    # stages, max-plus associative_scan recurrences, shard_map over the
     # cell axis. Two sub-runs, both parity-checked against host numpy:
     # the 64-cell reference (same scenario as above) and a >=1M-request
     # / >=256-cell scale run -- the CI-runner floor; 10M+ requests
@@ -907,6 +907,9 @@ def main() -> None:
         "the BENCH files",
     )
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.scenario is None or args.scenario == "all":
         scenario_names = None
     elif args.scenario == "none":

@@ -1,12 +1,15 @@
-"""Compiled fleet pipeline: the whole window loop as ONE jitted program.
+"""Compiled fleet pipeline: the whole window loop as jitted device stages.
 
 `FleetSimulator.run` steps the fleet in host numpy: a Python loop over
 (window, cell) batches, each doing a handful of small vectorized solves.
 This module moves the full pipeline -- per-device FIFO edge queues ->
 context lookup -> gate -> per-cell uplink (with Markov/trace link
-repricing) -> the shared K-server cloud tier -- into one jitted JAX
-program, `vmap`ped (and optionally `shard_map`ped over a "cells" mesh
-axis, see `repro.sharding.fleet_mesh`) over serving cells:
+repricing) -> the shared K-server cloud tier -- into three jitted JAX
+stages (edge + gate, uplink, cloud), `vmap`ped (and optionally
+`shard_map`ped over a "cells" mesh axis, see `repro.sharding.fleet_mesh`)
+over serving cells. The two orderings between the stages (offloads per
+uplink batch, the cloud's FIFO order) are exact stable sorts on the host
+(see `_uplink_order`):
 
 * every FIFO recurrence becomes a masked `lax.associative_scan` over the
   max-plus semiring (`repro.fleet.maxplus`, property-tested against a
@@ -92,7 +95,10 @@ class CompiledFleetSimulator(FleetSimulator):
     mesh: None = single-device `vmap`; a `jax.sharding.Mesh` with axis
     "cells" = `shard_map` over cells (cell count must divide the mesh
     size evenly); "auto" = `repro.sharding.fleet_mesh()` when more than
-    one device is visible.
+    one device is visible, and an error when the cell count does not
+    divide the device count -- it never drops to one device in silence.
+    `output_devices` holds the ids of the devices that held the last
+    window program's outputs.
     """
 
     def __init__(
@@ -123,6 +129,7 @@ class CompiledFleetSimulator(FleetSimulator):
             payload_nbytes=payload_nbytes, orchestrator=orchestrator, obs=obs,
         )
         self.mesh = mesh
+        self.output_devices: frozenset = frozenset()
         self._programs: dict = {}
 
     # ------------------------------------------------------------- helpers
@@ -132,17 +139,20 @@ class CompiledFleetSimulator(FleetSimulator):
         if self.mesh == "auto":
             import jax
 
-            if jax.device_count() > 1 and n_cells % jax.device_count() == 0:
-                from repro.sharding import fleet_mesh
+            if jax.device_count() == 1:
+                return None
+            from repro.sharding import fleet_mesh
 
-                return fleet_mesh()
-            return None
-        if n_cells % self.mesh.size != 0:
+            mesh = fleet_mesh()
+        else:
+            mesh = self.mesh
+        if n_cells % mesh.size != 0:
             raise ValueError(
                 f"{n_cells} cells do not shard evenly over a "
-                f"{self.mesh.size}-device mesh"
+                f"{mesh.size}-device mesh; pass mesh=None to run on one "
+                f"device, or a mesh whose size divides the cell count"
             )
-        return self.mesh
+        return mesh
 
     def _min_rate(self, net) -> float:
         if isinstance(net, MarkovNetwork):
@@ -331,7 +341,7 @@ class CompiledFleetSimulator(FleetSimulator):
                 )
             return out
 
-        def cell_fn(cell_id, arr, smp, dev, org, bl, valid, tbl):
+        def gate_fn(arr, smp, dev, org, valid, tbl):
             # --- edge tier: one masked max-plus chain per device lane.
             # Rows arrive in (window, origin) batch order, which is
             # exactly the host's carried-dev_free chain order.
@@ -345,13 +355,14 @@ class CompiledFleetSimulator(FleetSimulator):
             ctx = jnp.where(valid, ctx_at(tbl, org, edge_done), 0)
             conf = tbl["conf"][ctx, smp]
             on = conf >= tbl["p_tar"]
-            offl = valid & ~on
-            # --- uplink: sort offloads to the front in (batch, ready-time)
-            # order, then price each batch with the host's two-pass link
+            return edge_done, ctx, conf, on
+
+        def uplink_fn(cell_id, edge_done, offl, bl, order, tbl):
+            # --- uplink: offloads first in (batch, ready-time) order (the
+            # permutation `order` comes from the host, see `_uplink_order`),
+            # then price each batch with the host's two-pass link
             # repricing under a lax.scan carrying the uplink-free time.
             # That scan is the window loop, fused.
-            rowpos = jnp.arange(R)
-            order = jnp.lexsort((rowpos, edge_done, bl, ~offl))
             t_s = edge_done[order]
             o_s = offl[order]
             counts = jax.ops.segment_sum(
@@ -392,7 +403,7 @@ class CompiledFleetSimulator(FleetSimulator):
             up_comm = jnp.full(R + 1, jnp.nan).at[safe].set(
                 c_b.reshape(-1)
             )[:R]
-            return edge_done, ctx, conf, on, up_done, up_comm
+            return up_done, up_comm
 
         def bh_fn(cell_id, arr, smp, valid, tbl):
             # whole-fleet outage: nominal-rate cloud backhaul per origin
@@ -403,96 +414,62 @@ class CompiledFleetSimulator(FleetSimulator):
             ctx = jnp.where(valid, ctx_at(tbl, org, arr), 0)
             return ctx, done
 
-        def cells_fn(cell_ids, lane, bh, tbl):
-            outA = jax.vmap(
-                cell_fn, in_axes=(0, 0, 0, 0, 0, 0, 0, None)
-            )(cell_ids, lane["arr"], lane["smp"], lane["dev"], lane["org"],
-              lane["bl"], lane["valid"], tbl)
+        def gate_cells(cell_ids, lane, bh, tbl):
+            outA = jax.vmap(gate_fn, in_axes=(0, 0, 0, 0, 0, None))(
+                lane["arr"], lane["smp"], lane["dev"], lane["org"],
+                lane["valid"], tbl,
+            )
             outB = jax.vmap(bh_fn, in_axes=(0, 0, 0, 0, None))(
                 cell_ids, bh["arr"], bh["smp"], bh["valid"], tbl
             )
             return outA, outB
 
+        def uplink_cells(cell_ids, up, tbl):
+            return jax.vmap(uplink_fn, in_axes=(0, 0, 0, 0, 0, None))(
+                cell_ids, up["edge_done"], up["offl"], up["bl"],
+                up["order"], tbl,
+            )
+
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             sh = P("cells", None)
-            cells_fn = shard_map(
-                cells_fn,
+            rep = jax.tree_util.tree_map(lambda _: P(), self._tbl_struct)
+            gate_cells = jax.shard_map(
+                gate_cells,
                 mesh=mesh,
                 in_specs=(
                     P("cells"),
-                    {k: sh for k in
-                     ("arr", "smp", "dev", "org", "bl", "valid")},
+                    {k: sh for k in ("arr", "smp", "dev", "org", "valid")},
                     {k: sh for k in ("arr", "smp", "valid")},
-                    jax.tree_util.tree_map(lambda _: P(), self._tbl_struct),
+                    rep,
                 ),
-                out_specs=((sh,) * 6, (sh,) * 2),
-                check_rep=False,
+                out_specs=((sh,) * 4, (sh,) * 2),
+                check_vma=False,
+            )
+            uplink_cells = jax.shard_map(
+                uplink_cells,
+                mesh=mesh,
+                in_specs=(
+                    P("cells"),
+                    {k: sh for k in ("edge_done", "offl", "bl", "order")},
+                    rep,
+                ),
+                out_specs=(sh, sh),
+                check_vma=False,
             )
 
-        def program(cell_ids, lane, bh, tbl):
+        def gate_program(cell_ids, lane, bh, tbl):
             lane_in = {k: lane[k] for k in
-                       ("arr", "smp", "dev", "org", "bl", "valid")}
+                       ("arr", "smp", "dev", "org", "valid")}
             bh_in = {k: bh[k] for k in ("arr", "smp", "valid")}
-            (edge_done, ctx, conf, on, up_done, up_comm), (ctx_bh, bh_done) \
-                = cells_fn(cell_ids, lane_in, bh_in, tbl)
-            # --- shared cloud tier, solved once globally: stable sort by
-            # transfer completion (generation order breaks ties), K
-            # residue-class max-plus chains as the columns of a row-major
-            # (M, K) reshape, then unsort.
-            s_cloud = tbl["s_cloud"]
-            okA = (lane["valid"] & ~on).reshape(-1)
-            tA = up_done.reshape(-1)
-            sA = s_cloud * scale_at(tA)
-            okB = bh["valid"].reshape(-1)
-            tB = bh_done.reshape(-1)
-            sB = s_cloud * scale_at(tB)
-            t = jnp.concatenate([tA, tB])
-            ok = jnp.concatenate([okA, okB])
-            sv = jnp.concatenate([sA, sB])
-            gid = jnp.concatenate(
-                [lane["gid"].reshape(-1), bh["gid"].reshape(-1)]
+            (edge_done, ctx, conf, on), (ctx_bh, bh_done) = gate_cells(
+                cell_ids, lane_in, bh_in, tbl
             )
-            ready = jnp.concatenate(
-                [edge_done.reshape(-1), bh["arr"].reshape(-1)]
-            )
-            n = t.shape[0]
-            fi = jnp.arange(n)
-            gorder = jnp.lexsort((fi, ready, gid, ~ok))
-            grank = jnp.zeros(n, fi.dtype).at[gorder].set(fi)
-            key_t = jnp.where(ok, t, jnp.inf)
-            order2 = jnp.lexsort((grank, key_t))
-            t_sorted = key_t[order2]
-            s_sorted = jnp.where(ok, sv, 0.0)[order2]
-            pad = N_pad - n
-            if pad:
-                t_sorted = jnp.concatenate(
-                    [t_sorted, jnp.full(pad, jnp.inf)]
-                )
-                s_sorted = jnp.concatenate([s_sorted, jnp.zeros(pad)])
-            mat_t = t_sorted.reshape(-1, K)
-            mat_s = s_sorted.reshape(-1, K)
-
-            def combine(x, y):
-                a1, b1 = x
-                a2, b2 = y
-                return a1 + a2, jnp.maximum(b1 + a2, b2)
-
-            a_s, b_s = lax.associative_scan(
-                combine, (mat_s, mat_t + mat_s), axis=0
-            )
-            done_sorted = jnp.maximum(b_s, a_s).reshape(-1)[:n]
-            cloud = jnp.zeros(n).at[order2].set(done_sorted)
-            nA = C * R
             res = dict(
                 edge_done=edge_done, ctx=ctx, conf=conf, on=on,
-                up_done=up_done, up_comm=up_comm,
-                s_eff=sA.reshape(C, R), cloud=cloud[:nA].reshape(C, R),
                 ctx_bh=ctx_bh, bh_done=bh_done,
-                s_eff_bh=sB.reshape(C, RB),
-                cloud_bh=cloud[nA:].reshape(C, RB),
+                s_eff_bh=tbl["s_cloud"] * scale_at(bh_done),
             )
             if cal_bins:
                 # --- reliability-bin sketch, accumulated IN the fused
@@ -525,9 +502,85 @@ class CompiledFleetSimulator(FleetSimulator):
                 res["cal"] = calsum.reshape(7, C, n_ctx, nb1)
             return res
 
-        prog = jax.jit(program)
+        def uplink_program(cell_ids, up, tbl):
+            up_done, up_comm = uplink_cells(cell_ids, up, tbl)
+            # service times at the shared cloud tier; the tier itself is
+            # solved by `cloud_fn` once the host has ordered its jobs
+            return dict(up_done=up_done, up_comm=up_comm,
+                        s_eff=tbl["s_cloud"] * scale_at(up_done))
+
+        def cloud_fn(mat_t, mat_s):
+            # the shared cloud tier: K residue-class max-plus chains as
+            # the columns of the row-major (M, K) matrix of jobs in FIFO
+            # order (sorted on the host, see `_cloud_done`)
+            def combine(x, y):
+                a1, b1 = x
+                a2, b2 = y
+                return a1 + a2, jnp.maximum(b1 + a2, b2)
+
+            a_s, b_s = lax.associative_scan(
+                combine, (mat_s, mat_t + mat_s), axis=0
+            )
+            return jnp.maximum(b_s, a_s).reshape(-1)
+
+        prog = (jax.jit(gate_program), jax.jit(uplink_program),
+                jax.jit(cloud_fn))
         self._programs[S] = prog
         return prog
+
+    # The orderings between the device stages are computed here, on the
+    # host, with numpy's exact stable lexsort: the TPU compiler's time for
+    # a sort grows with the number of elements sorted (about 50 s for a
+    # 2^16-element float32 sort, over 6 min for the per-cell float64
+    # lexsort at the reference fleet's 64 x 2048), while every stage
+    # without a sort compiles in seconds. The orders are permutations,
+    # so the device stages compute exactly what one program would.
+    @staticmethod
+    def _uplink_order(edge_done, offl, bl):
+        """Per cell, the row permutation that puts offloads first, in
+        (uplink batch, edge completion, row) order: (C, R) row indices."""
+        C, R = edge_done.shape
+        o = np.lexsort((
+            np.tile(np.arange(R), C), edge_done.ravel(), bl.ravel(),
+            ~offl.ravel(), np.repeat(np.arange(C), R),
+        ))
+        return o.reshape(C, R) - (np.arange(C) * R)[:, None]
+
+    @staticmethod
+    def _cloud_done(out, lane, bh, K: int, N_pad: int, cloud_fn):
+        """Shared cloud tier completion times, (C, R) and (C, RB).
+
+        Jobs are stably ordered by transfer completion, generation order
+        breaking ties, exactly as the host simulator queues them; the K
+        residue-class chains run on the device (`cloud_fn`).
+        """
+        C, R = out["edge_done"].shape
+        okA = (lane["valid"] & ~out["on"]).reshape(-1)
+        okB = bh["valid"].reshape(-1)
+        ok = np.concatenate([okA, okB])
+        t = np.concatenate([out["up_done"].reshape(-1), out["bh_done"].reshape(-1)])
+        sv = np.concatenate([out["s_eff"].reshape(-1), out["s_eff_bh"].reshape(-1)])
+        gid = np.concatenate([lane["gid"].reshape(-1), bh["gid"].reshape(-1)])
+        ready = np.concatenate(
+            [out["edge_done"].reshape(-1), bh["arr"].reshape(-1)]
+        )
+        n = t.shape[0]
+        fi = np.arange(n)
+        gorder = np.lexsort((fi, ready, gid, ~ok))
+        grank = np.empty(n, fi.dtype)
+        grank[gorder] = fi
+        key_t = np.where(ok, t, np.inf)
+        order2 = np.lexsort((grank, key_t))
+        t_sorted = np.full(N_pad, np.inf)
+        s_sorted = np.zeros(N_pad)
+        t_sorted[:n] = key_t[order2]
+        s_sorted[:n] = np.where(ok, sv, 0.0)[order2]
+        done_sorted = np.asarray(
+            cloud_fn(t_sorted.reshape(-1, K), s_sorted.reshape(-1, K))
+        )[:n]
+        cloud = np.empty(n)
+        cloud[order2] = done_sorted
+        return cloud[: C * R].reshape(C, R), cloud[C * R:].reshape(C, -1)
 
     # ----------------------------------------------------------------- run
     def run(self) -> FleetTelemetry:
@@ -706,11 +759,26 @@ class CompiledFleetSimulator(FleetSimulator):
         )
         prog = self._program(S)
 
-        from jax.experimental import enable_x64
+        import jax
 
-        with enable_x64():
-            out = prog(np.arange(C, dtype=np.int64), lane, bh, tbl)
-            out = {k: np.asarray(v) for k, v in out.items()}
+        gate_prog, uplink_prog, cloud_prog = prog
+        cell_ids = np.arange(C, dtype=np.int64)
+        with jax.enable_x64(True):
+            gate_out = gate_prog(cell_ids, lane, bh, tbl)
+            out = {k: np.asarray(v) for k, v in gate_out.items()}
+            offl = lane["valid"] & ~out["on"]
+            up_out = uplink_prog(cell_ids, dict(
+                edge_done=gate_out["edge_done"], offl=offl, bl=lane["bl"],
+                order=self._uplink_order(out["edge_done"], offl, lane["bl"]),
+            ), tbl)
+            self.output_devices = frozenset(
+                d.id for v in (*gate_out.values(), *up_out.values())
+                for d in v.sharding.device_set
+            )
+            out.update({k: np.asarray(v) for k, v in up_out.items()})
+            out["cloud"], out["cloud_bh"] = self._cloud_done(
+                out, lane, bh, K, N_pad, cloud_prog
+            )
 
         # ---- host recovery: per-request verdict columns (exact numpy
         # table math, same as the host simulator's gate aftermath)

@@ -113,7 +113,7 @@ def fifo_done_maxplus(t, service, free_s: float = 0.0) -> np.ndarray:
     Pads to the next power of two so a sweep over chain lengths 1..N costs at
     most log2(N)+1 compilations, mirroring the gate-path padding contract.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     t = np.asarray(t, dtype=np.float64)
     service = np.asarray(service, dtype=np.float64)
@@ -127,7 +127,7 @@ def fifo_done_maxplus(t, service, free_s: float = 0.0) -> np.ndarray:
     tp[:n] = t
     sp[:n] = service
     mask[:n] = True
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _scan_fn()(tp, sp, mask, np.float64(free_s))
     return np.asarray(out)[:n]
 

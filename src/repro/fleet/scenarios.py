@@ -146,8 +146,8 @@ def run_fleet(
     `obs` attaches a `repro.obs.Observability` bundle (sampled traces,
     decision audit log, metrics); None (the default) is zero-perturbation.
 
-    backend="compiled" runs the whole window pipeline device-side as one
-    jitted program (`repro.fleet.compiled.CompiledFleetSimulator`,
+    backend="compiled" runs the whole window pipeline device-side in
+    jitted stages (`repro.fleet.compiled.CompiledFleetSimulator`,
     parity-pinned against the host simulator); it serves static
     deployments only, so it rejects `with_controller` and rollouts.
     """

@@ -27,9 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG = -1e30
 
 
@@ -51,21 +48,21 @@ def _kernel(temp_ref, labels_ref, z_ref, e1_ref, e2_ref, zy_ref, nll_ref,
     zraw = z_ref[:].astype(jnp.float32)  # (R, C)
     u = zraw / t
 
-    # --- label logit: the tile that contains column y_r contributes it ---
-    labels = labels_ref[:]  # (R,)
+    # --- label logit: the tile that contains column y_r contributes it;
+    # labels and per-row state are (R, 1) ---
     col0 = j * C
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, zraw.shape, 1)
-    hit = cols == labels[:, None]
-    zy_s[:] = zy_s[:] + jnp.sum(jnp.where(hit, zraw, 0.0), axis=1)
+    hit = cols == labels_ref[:]
+    zy_s[:] = zy_s[:] + jnp.sum(jnp.where(hit, zraw, 0.0), axis=1, keepdims=True)
 
     # --- streaming max rescale ---
     m_old = m_s[:]
-    m_new = jnp.maximum(m_old, jnp.max(u, axis=1))
+    m_new = jnp.maximum(m_old, jnp.max(u, axis=1, keepdims=True))
     scale = jnp.exp(m_old - m_new)
-    e = jnp.exp(u - m_new[:, None])
-    s_s[:] = s_s[:] * scale + jnp.sum(e, axis=1)
-    w1_s[:] = w1_s[:] * scale + jnp.sum(zraw * e, axis=1)
-    w2_s[:] = w2_s[:] * scale + jnp.sum(zraw * zraw * e, axis=1)
+    e = jnp.exp(u - m_new)
+    s_s[:] = s_s[:] * scale + jnp.sum(e, axis=1, keepdims=True)
+    w1_s[:] = w1_s[:] * scale + jnp.sum(zraw * e, axis=1, keepdims=True)
+    w2_s[:] = w2_s[:] * scale + jnp.sum(zraw * zraw * e, axis=1, keepdims=True)
     m_s[:] = m_new
 
     @pl.when(j == nj - 1)
@@ -78,21 +75,21 @@ def _kernel(temp_ref, labels_ref, z_ref, e1_ref, e2_ref, zy_ref, nll_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_cols", "interpret"))
-def calib_nll_kernel(logits, labels, temperature,
-                     block_rows: int = 8, block_cols: int = 512,
-                     interpret: bool = True):
-    """logits (rows, vocab), labels (rows,) int32, temperature scalar.
+def calib_nll_kernel(logits, labels, temperature, *, interpret: bool,
+                     block_rows: int = 8, block_cols: int = 512):
+    """logits (rows, vocab), labels (rows, 1) int32, temperature scalar.
 
-    Returns (e1, e2, zy, nll) per row; rows/vocab must be tile multiples
-    (ops.py pads: rows with label 0 / NEG logits, masked out afterwards).
+    Returns (e1, e2, zy, nll), each (rows, 1); rows/vocab must be tile
+    multiples (ops.py pads: rows with label 0 / large negative logits,
+    masked out afterwards).
     """
     rows, vocab = logits.shape
     assert rows % block_rows == 0 and vocab % block_cols == 0
     grid = (rows // block_rows, vocab // block_cols)
     temp = jnp.asarray(temperature, jnp.float32).reshape(1, 1)
-    row_spec = pl.BlockSpec((block_rows,), lambda i, j: (i,))
+    row_spec = pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0))
     out_shapes = tuple(
-        jax.ShapeDtypeStruct((rows,), jnp.float32) for _ in range(4)
+        jax.ShapeDtypeStruct((rows, 1), jnp.float32) for _ in range(4)
     )
     return pl.pallas_call(
         _kernel,
@@ -104,8 +101,8 @@ def calib_nll_kernel(logits, labels, temperature,
         ],
         out_specs=(row_spec, row_spec, row_spec, row_spec),
         out_shape=out_shapes,
-        scratch_shapes=[pltpu.VMEM((block_rows,), jnp.float32) for _ in range(5)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((block_rows, 1), jnp.float32) for _ in range(5)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

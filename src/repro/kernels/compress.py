@@ -26,12 +26,17 @@ would otherwise flush its whole tile to zeros with an inf scale), and an
 all-zero tile stores scale 0 but divides by 1, so encode never divides
 by zero. Level 0 is the identity and never reaches these kernels.
 
-Tiling: rows block 8 (fp32 sublane) x features block 512 lanes; every
-(8, 512) block owns four whole scale groups, so the grid is fully
-parallel (no cross-tile carry, unlike the online-softmax gate kernel).
-The group reshape (8, 512) -> (8, 4, 128) stays within the lane axis.
-`interpret=True` executes on CPU for validation; ops-level wrappers pass
-`interpret=not _is_tpu()` exactly as `ops.exit_gate` does.
+Layout: Mosaic lowers no lane split, so the wrapper hands the kernel
+a "plane" view of the payload. Features index as (group g, word w, slot
+k) with g the 128-wide scale group, w the uint32 word inside it and k
+the value's slot in that word; the jitted wrapper transposes
+(rows, G, W, per) -> (rows, per, W, G). In that view the scale is a max
+over the leading and sublane axes, packing is an elementwise OR of the
+`per` planes, and every block keeps G on the lanes: words (8, W, G),
+scales (8, 1, G), W = 32 (int8) or 16 (int4). G is one block when it is
+at most 128 and 128-lane blocks otherwise; the grid is fully parallel.
+The transposes back to the wire format run in the same jitted wrapper.
+`ops.interpret_mode()` decides interpret mode, as for the gate kernel.
 """
 from __future__ import annotations
 
@@ -45,17 +50,11 @@ import numpy as _np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import interpret_mode
 from repro.kernels.ref import CODEC_BITS, CODEC_TILE, _codec_layout
-
-# renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 #: the codec's public level axis: 0 = identity float32, 1 = int8, 2 = int4
 LEVELS = (0, 1, 2)
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def compressed_nbytes(n_elements: int, level: int) -> int:
@@ -80,107 +79,182 @@ def scaled_payload_nbytes(raw_nbytes: int, level: int) -> int:
 
 
 # ---------------------------------------------------------------- kernels
+def _split12(a):
+    """a = hi + lo exactly, hi keeping the top 12 significant bits."""
+    hi = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(a, jnp.uint32) & jnp.uint32(0xFFFFF000),
+        jnp.float32,
+    )
+    return hi, a - hi
+
+
+def _residual(z, c, s):
+    """z - c * s, exact for c within a few ulps of z / s: Dekker's
+    two-product on 12-bit halves (no fused multiply-add needed), and
+    z - c * s is then Sterbenz-exact."""
+    p = c * s
+    ch, cl = _split12(c)
+    sh, sl = _split12(s)
+    e = ((ch * sh - p) + ch * sl + cl * sh) + cl * sl
+    return (z - p) - e
+
+
+def _nearest_quotient(z, s, y):
+    """The correctly rounded (to nearest, ties to even) float32 z / s,
+    given an approximate quotient y within a few ulps of it.
+
+    The TPU's float32 divide is not correctly rounded (on a TPU v5e it
+    differs from the correctly rounded quotient on about a third of
+    random operands), and a quotient off in its last bits lands on the
+    other side of a rounding boundary now and then -- which changes a
+    quantized code. Multiplies, adds and bit operations are
+    exact, so one Newton step followed by a choice among y and its two
+    neighbours by exact residual gives numpy's quotient bit for bit,
+    while no product underflows.
+    """
+    y = y + _residual(z, y, s) / s
+    bits = jax.lax.bitcast_convert_type(y, jnp.int32)
+    best, r_best = y, jnp.abs(_residual(z, y, s))
+    odd_best = bits & 1  # int32, not bool: Mosaic selects no i1 vectors
+    for nb in (bits - 1, bits + 1):
+        c = jax.lax.bitcast_convert_type(nb, jnp.float32)
+        r = jnp.abs(_residual(z, c, s))  # NaN (never chosen) below 0
+        odd = nb & 1
+        take = (r < r_best) | ((r == r_best) & (odd < odd_best))
+        best = jnp.where(take, c, best)
+        r_best = jnp.where(take, r, r_best)
+        odd_best = jnp.where(take, odd, odd_best)
+    return best
+
+
 def _encode_kernel(x_ref, words_ref, scale_ref, *, bits: int):
     per = 32 // bits
     qmax = jnp.float32((1 << (bits - 1)) - 1)
     mask = jnp.uint32((1 << bits) - 1)
-    z = x_ref[:].astype(jnp.float32)  # (R, C)
+    z = x_ref[...].astype(jnp.float32)  # (R, per, W, G)
     z = jnp.where(jnp.isfinite(z), z, jnp.float32(0.0))
-    R, C = z.shape
-    g = C // CODEC_TILE
-    zt = z.reshape(R, g, CODEC_TILE)
+    amax = jnp.max(jnp.max(jnp.abs(z), axis=1), axis=1, keepdims=True)
     # reciprocal-multiply, matching ref.encode_codec_ref bit-for-bit
-    scale = jnp.max(jnp.abs(zt), axis=2) * jnp.float32(_np.float32(1.0) / _np.float32((1 << (bits - 1)) - 1))
-    safe = jnp.where(scale > 0, scale, jnp.float32(1.0))
-    q = jnp.clip(jnp.round(zt / safe[:, :, None]), -qmax, qmax)
-    q = q.astype(jnp.int32).reshape(R, C // per, per)
-    w = jnp.zeros((R, C // per), jnp.uint32)
+    scale = amax * jnp.float32(_np.float32(1.0) / _np.float32((1 << (bits - 1)) - 1))
+    safe = jnp.where(scale > 0, scale, jnp.float32(1.0))  # (R, 1, G)
+    w = jnp.zeros(words_ref.shape, jnp.uint32)
     for k in range(per):  # static unroll: 4 (int8) or 8 (int4) ors
-        w = w | ((q[:, :, k].astype(jnp.uint32) & mask) << jnp.uint32(bits * k))
-    words_ref[:] = w
-    scale_ref[:] = scale
+        zk = z[:, k]
+        q = _nearest_quotient(zk, safe, zk / safe)
+        q = jnp.clip(jnp.round(q), -qmax, qmax).astype(jnp.int32)
+        w = w | ((q.astype(jnp.uint32) & mask) << jnp.uint32(bits * k))
+    words_ref[...] = w
+    scale_ref[...] = scale
 
 
 def _decode_kernel(words_ref, scale_ref, out_ref, *, bits: int):
     per = 32 // bits
     half, full = 1 << (bits - 1), 1 << bits
     mask = jnp.uint32(full - 1)
-    w = words_ref[:]  # (R, C // per) uint32
-    vs = []
+    w = words_ref[...]  # (R, W, G) uint32
+    scale = scale_ref[...]  # (R, 1, G)
     for k in range(per):
         u = ((w >> jnp.uint32(bits * k)) & mask).astype(jnp.int32)
-        vs.append(jnp.where(u >= half, u - full, u))
-    R, nw = w.shape
-    v = jnp.stack(vs, axis=-1).reshape(R, nw * per)
-    zt = v.reshape(R, -1, CODEC_TILE).astype(jnp.float32)
-    out_ref[:] = (zt * scale_ref[:][:, :, None]).reshape(R, nw * per)
+        v = jnp.where(u >= half, u - full, u).astype(jnp.float32)
+        out_ref[:, k] = v * scale
 
 
-@functools.partial(
-    jax.jit, static_argnames=("bits", "block_rows", "block_cols", "interpret")
-)
-def encode_pallas(
-    z, bits: int, block_rows: int = 8, block_cols: int = 512,
-    interpret: bool = True,
-):
-    """z: (rows, cols) float32, rows % block_rows == 0, cols % block_cols
-    == 0. Returns (words uint32, scales float32) covering all of z."""
-    rows, cols = z.shape
-    assert rows % block_rows == 0 and cols % block_cols == 0
-    per = 32 // bits
-    grid = (rows // block_rows, cols // block_cols)
+def _group_block(n_groups: int) -> int:
+    """Lane block over scale groups: all of them up to 128, else 128."""
+    return n_groups if n_groups <= 128 else 128
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "interpret", "block_rows"))
+def encode_pallas(planes, bits: int, *, interpret: bool, block_rows: int = 8):
+    """planes: (rows, per, W, G) float32 plane view (module docstring),
+    rows % block_rows == 0, G one lane block or a multiple of 128.
+    Returns words (rows, W, G) uint32 and scales (rows, 1, G) float32."""
+    rows, per, nw, ng = planes.shape
+    bg = _group_block(ng)
+    assert per == 32 // bits and nw * per == CODEC_TILE
+    assert rows % block_rows == 0 and ng % bg == 0
     return pl.pallas_call(
         functools.partial(_encode_kernel, bits=bits),
-        grid=grid,
+        grid=(rows // block_rows, ng // bg),
         in_specs=[
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
+            pl.BlockSpec((block_rows, per, nw, bg), lambda i, j: (i, 0, 0, j)),
         ],
         out_specs=(
-            pl.BlockSpec((block_rows, block_cols // per), lambda i, j: (i, j)),
-            pl.BlockSpec(
-                (block_rows, block_cols // CODEC_TILE), lambda i, j: (i, j)
-            ),
+            pl.BlockSpec((block_rows, nw, bg), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((block_rows, 1, bg), lambda i, j: (i, 0, j)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((rows, cols // per), jnp.uint32),
-            jax.ShapeDtypeStruct((rows, cols // CODEC_TILE), jnp.float32),
+            jax.ShapeDtypeStruct((rows, nw, ng), jnp.uint32),
+            jax.ShapeDtypeStruct((rows, 1, ng), jnp.float32),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(z)
+    )(planes)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("bits", "block_rows", "block_cols", "interpret")
-)
-def decode_pallas(
-    words, scales, bits: int, block_rows: int = 8, block_cols: int = 512,
-    interpret: bool = True,
-):
-    """Inverse of `encode_pallas`; returns (rows, cols) float32."""
+@functools.partial(jax.jit, static_argnames=("bits", "interpret", "block_rows"))
+def decode_pallas(words, scales, bits: int, *, interpret: bool,
+                  block_rows: int = 8):
+    """Inverse of `encode_pallas`: words (rows, W, G), scales (rows, 1, G)
+    -> float32 planes (rows, per, W, G)."""
     per = 32 // bits
-    rows, nw = words.shape
-    cols = nw * per
-    assert rows % block_rows == 0 and cols % block_cols == 0
-    grid = (rows // block_rows, cols // block_cols)
+    rows, nw, ng = words.shape
+    bg = _group_block(ng)
+    assert nw * per == CODEC_TILE
+    assert rows % block_rows == 0 and ng % bg == 0
     return pl.pallas_call(
         functools.partial(_decode_kernel, bits=bits),
-        grid=grid,
+        grid=(rows // block_rows, ng // bg),
         in_specs=[
-            pl.BlockSpec((block_rows, block_cols // per), lambda i, j: (i, j)),
-            pl.BlockSpec(
-                (block_rows, block_cols // CODEC_TILE), lambda i, j: (i, j)
-            ),
+            pl.BlockSpec((block_rows, nw, bg), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((block_rows, 1, bg), lambda i, j: (i, 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((block_rows, per, nw, bg),
+                               lambda i, j: (i, 0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((rows, per, nw, ng), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
     )(words, scales)
+
+
+def _padded_dims(rows: int, n_groups: int, block_rows: int = 8):
+    """(rows, groups) padded to the kernels' block multiples."""
+    bg = _group_block(n_groups)
+    return -(-rows // block_rows) * block_rows, -(-n_groups // bg) * bg
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _encode_wire(z, bits: int):
+    """(rows, cols) float32 -> wire (words, scales) of `ref.encode_codec_ref`."""
+    per = 32 // bits
+    nw = CODEC_TILE // per
+    rows, cols = z.shape
+    ng = -(-cols // CODEC_TILE)
+    rp, gp = _padded_dims(rows, ng)
+    z = jnp.pad(z, ((0, rp - rows), (0, gp * CODEC_TILE - cols)))
+    planes = z.reshape(rp, gp, nw, per).transpose(0, 3, 2, 1)
+    words, scales = encode_pallas(planes, bits, interpret=interpret_mode())
+    words = words.transpose(0, 2, 1)[:rows, :ng].reshape(rows, ng * nw)
+    return words, scales[:rows, 0, :ng]
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _decode_wire(words, scales, bits: int):
+    """Wire (words, scales) -> (rows, groups * 128) float32."""
+    per = 32 // bits
+    nw = CODEC_TILE // per
+    rows, ng = scales.shape
+    rp, gp = _padded_dims(rows, ng)
+    w = jnp.pad(words.reshape(rows, ng, nw), ((0, rp - rows), (0, gp - ng), (0, 0)))
+    sc = jnp.pad(scales, ((0, rp - rows), (0, gp - ng)))[:, None, :]
+    planes = decode_pallas(w.transpose(0, 2, 1), sc, bits,
+                           interpret=interpret_mode())
+    out = planes.transpose(0, 3, 2, 1).reshape(rp, gp * CODEC_TILE)
+    return out[:rows, : ng * CODEC_TILE]
 
 
 # ----------------------------------------------------------- public wrappers
@@ -201,53 +275,30 @@ class EncodedPayload:
         return rows * compressed_nbytes(cols, self.level)
 
 
-def encode(x, level: int, block_rows: int = 8, block_cols: int = 512) -> EncodedPayload:
+def encode(x, level: int) -> EncodedPayload:
     """Encode an arbitrary-shape float payload through the Pallas kernel
-    (interpret mode off-TPU). The emitted words/scales are sliced to the
-    128-aligned wire format of `ref.encode_codec_ref`, bit-exactly."""
+    (interpret mode off-TPU) into the 128-aligned wire format of
+    `ref.encode_codec_ref`, bit-exactly."""
     level = int(level)
     if level == 0:
         raise ValueError("level 0 is the identity; nothing to encode")
-    bits = CODEC_BITS[level]
-    per = 32 // bits
     x = jnp.asarray(x)
     rows, cols = _codec_layout(x.shape)
-    z = x.reshape(rows, cols).astype(jnp.float32)
-    cols128 = -(-cols // CODEC_TILE) * CODEC_TILE
-    pr = (-rows) % block_rows
-    pc = (-cols) % block_cols
-    if pr or pc:
-        z = jnp.pad(z, ((0, pr), (0, pc)))
-    words, scales = encode_pallas(
-        z, bits, block_rows=block_rows, block_cols=block_cols,
-        interpret=not _is_tpu(),
+    words, scales = _encode_wire(
+        x.reshape(rows, cols).astype(jnp.float32), CODEC_BITS[level]
     )
     return EncodedPayload(
-        words=words[:rows, : cols128 * bits // 32],
-        scales=scales[:rows, : cols128 // CODEC_TILE],
-        shape=tuple(int(d) for d in x.shape),
-        level=level,
+        words=words, scales=scales,
+        shape=tuple(int(d) for d in x.shape), level=level,
     )
 
 
-def decode(enc: EncodedPayload, block_rows: int = 8, block_cols: int = 512):
+def decode(enc: EncodedPayload):
     """Decode an `EncodedPayload` back to float32 in its original shape."""
-    bits = CODEC_BITS[int(enc.level)]
-    per = 32 // bits
     rows, cols = _codec_layout(enc.shape)
-    words = jnp.asarray(enc.words)
-    scales = jnp.asarray(enc.scales)
-    nw, ng = words.shape[1], scales.shape[1]
-    pr = (-rows) % block_rows
-    pw = (-(nw * per)) % block_cols
-    if pr or pw:
-        words = jnp.pad(words, ((0, pr), (0, pw // per)))
-        scales = jnp.pad(scales, ((0, pr), (0, pw // CODEC_TILE)))
-    out = decode_pallas(
-        words, scales, bits, block_rows=block_rows, block_cols=block_cols,
-        interpret=not _is_tpu(),
-    )
-    return out[:rows, :cols].reshape(enc.shape)
+    out = _decode_wire(jnp.asarray(enc.words), jnp.asarray(enc.scales),
+                       CODEC_BITS[int(enc.level)])
+    return out[:, :cols].reshape(enc.shape)
 
 
 def roundtrip(x, level: int):

@@ -33,9 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG = -1e30
 
 
@@ -55,25 +52,26 @@ def _kernel(temp_ref, z_ref, conf_ref, ent_ref, idx_ref, m_s, s_s, w_s, bv_s, bi
     t = temp_ref[0, 0]
     z = z_ref[:].astype(jnp.float32) / t  # (R, C)
 
-    # --- running max / rescale ---
-    m_old = m_s[:]  # (R,)
-    tile_max = jnp.max(z, axis=1)
+    # --- running max / rescale; per-row state is (R, 1) ---
+    m_old = m_s[:]
+    tile_max = jnp.max(z, axis=1, keepdims=True)
     m_new = jnp.maximum(m_old, tile_max)
     scale = jnp.exp(m_old - m_new)
     s_old = s_s[:] * scale
     w_old = (w_s[:] + (m_old - m_new) * s_s[:]) * scale
 
-    u = z - m_new[:, None]
+    u = z - m_new
     e = jnp.exp(u)
-    s_s[:] = s_old + jnp.sum(e, axis=1)
-    w_s[:] = w_old + jnp.sum(u * e, axis=1)
+    s_s[:] = s_old + jnp.sum(e, axis=1, keepdims=True)
+    w_s[:] = w_old + jnp.sum(u * e, axis=1, keepdims=True)
     m_s[:] = m_new
 
-    # --- streaming argmax (on raw logits; T > 0 preserves argmax) ---
-    tile_arg = jnp.argmax(z, axis=1).astype(jnp.int32)
-    tile_best = tile_max
-    better = tile_best > bv_s[:]
-    bv_s[:] = jnp.where(better, tile_best, bv_s[:])
+    # --- streaming argmax (T > 0 preserves argmax): first column that
+    # attains the tile max, as jnp.argmax breaks ties ---
+    cols = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+    tile_arg = jnp.min(jnp.where(z == tile_max, cols, C), axis=1, keepdims=True)
+    better = tile_max > bv_s[:]
+    bv_s[:] = jnp.where(better, tile_max, bv_s[:])
     bi_s[:] = jnp.where(better, tile_arg + j * C, bi_s[:])
 
     @pl.when(j == nj - 1)
@@ -86,9 +84,12 @@ def _kernel(temp_ref, z_ref, conf_ref, ent_ref, idx_ref, m_s, s_s, w_s, bv_s, bi
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_cols", "interpret"))
 def exit_gate_kernel(
-    logits, temperature, block_rows: int = 8, block_cols: int = 512, interpret: bool = True
+    logits, temperature, *, interpret: bool, block_rows: int = 8,
+    block_cols: int = 512,
 ):
-    """logits: (rows, vocab); temperature: scalar. Returns (conf, ent, idx).
+    """logits: (rows, vocab); temperature: scalar. Returns (conf, ent, idx),
+    each (rows, 1): per-row values live on the sublane axis, the 2-D layout
+    the TPU tiling accepts for a row block.
 
     rows must be a multiple of block_rows and vocab of block_cols (ops.py
     pads). interpret=True executes on CPU for validation; on TPU pass False.
@@ -99,11 +100,11 @@ def exit_gate_kernel(
     temp = jnp.asarray(temperature, jnp.float32).reshape(1, 1)
 
     out_shapes = (
-        jax.ShapeDtypeStruct((rows,), jnp.float32),  # confidence
-        jax.ShapeDtypeStruct((rows,), jnp.float32),  # entropy
-        jax.ShapeDtypeStruct((rows,), jnp.int32),  # argmax
+        jax.ShapeDtypeStruct((rows, 1), jnp.float32),  # confidence
+        jax.ShapeDtypeStruct((rows, 1), jnp.float32),  # entropy
+        jax.ShapeDtypeStruct((rows, 1), jnp.int32),  # argmax
     )
-    row_spec = pl.BlockSpec((block_rows,), lambda i, j: (i,))
+    row_spec = pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0))
     return pl.pallas_call(
         _kernel,
         grid=grid,
@@ -114,13 +115,13 @@ def exit_gate_kernel(
         out_specs=(row_spec, row_spec, row_spec),
         out_shape=out_shapes,
         scratch_shapes=[
-            pltpu.VMEM((block_rows,), jnp.float32),  # running max
-            pltpu.VMEM((block_rows,), jnp.float32),  # S
-            pltpu.VMEM((block_rows,), jnp.float32),  # W
-            pltpu.VMEM((block_rows,), jnp.float32),  # best value
-            pltpu.VMEM((block_rows,), jnp.int32),  # best index
+            pltpu.VMEM((block_rows, 1), jnp.float32),  # running max
+            pltpu.VMEM((block_rows, 1), jnp.float32),  # S
+            pltpu.VMEM((block_rows, 1), jnp.float32),  # W
+            pltpu.VMEM((block_rows, 1), jnp.float32),  # best value
+            pltpu.VMEM((block_rows, 1), jnp.int32),  # best index
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

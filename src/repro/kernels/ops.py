@@ -14,8 +14,14 @@ import jax.numpy as jnp
 from repro.kernels.exit_gate import NEG, exit_gate_kernel
 
 
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in interpret mode: off the TPU only.
+
+    The one place this is decided. On a TPU every kernel is compiled by
+    Mosaic, and a kernel the compiler refuses fails the call -- nothing
+    falls back to the interpreter or to a jnp path there.
+    """
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_cols"))
@@ -42,11 +48,11 @@ def exit_gate(logits, temperature=1.0, block_rows: int = 8, block_cols: int = 51
         temperature,
         block_rows=block_rows,
         block_cols=block_cols,
-        interpret=not _is_tpu(),
+        interpret=interpret_mode(),
     )
-    conf = conf[:rows].reshape(shape[:-1])
-    ent = ent[:rows].reshape(shape[:-1])
-    idx = idx[:rows].reshape(shape[:-1])
+    conf = conf[:rows, 0].reshape(shape[:-1])
+    ent = ent[:rows, 0].reshape(shape[:-1])
+    idx = idx[:rows, 0].reshape(shape[:-1])
     return conf, idx, ent
 
 
@@ -72,10 +78,10 @@ def calib_stats(logits, labels, temperature, block_rows: int = 8, block_cols: in
         z = jnp.pad(z, ((0, pr), (0, pc)), constant_values=-3e4)
         y = jnp.pad(y, (0, pr))
     e1, e2, zy, nll = calib_nll_kernel(
-        z, y, temperature, block_rows=block_rows, block_cols=block_cols,
-        interpret=not _is_tpu(),
+        z, y[:, None], temperature, block_rows=block_rows,
+        block_cols=block_cols, interpret=interpret_mode(),
     )
-    e1, e2, zy, nll = e1[:rows], e2[:rows], zy[:rows], nll[:rows]
+    e1, e2, zy, nll = (a[:rows, 0] for a in (e1, e2, zy, nll))
     t = jnp.asarray(temperature, jnp.float32)
     var = e2 - e1 * e1
     d1 = jnp.mean((zy - e1) / (t * t))

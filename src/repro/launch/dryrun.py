@@ -40,12 +40,6 @@ from repro.training.loop import make_train_step
 from repro.launch.serve import make_prefill_step, make_serve_step
 
 
-def _mesh_context(mesh):
-    """jax.sharding.set_mesh is newer-jax; a Mesh is itself the context
-    manager on 0.4.x."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
 ASSIGNED = [a for a in list_archs() if a != "b_alexnet"]
 
 COLLECTIVES = (
@@ -198,7 +192,7 @@ def run_one(
     mesh_name = "2x16x16" if multi_pod else "16x16"
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
-    with _mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         cfg, jitted, args = build_lowering(
             arch, shape_name, mesh, zero1=zero1, variant=variant
         )
@@ -206,8 +200,6 @@ def run_one(
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
     coll, coll_counts = collective_bytes(hlo)
     # Recursive while-trip-count-aware cost model (XLA cost_analysis counts

@@ -64,6 +64,25 @@ def test_encode_matches_oracle_bitexact(level, shape):
     np.testing.assert_array_equal(out, ref)
 
 
+@pytest.mark.parametrize("ulps", [-2, -1, 0, 1, 2])
+def test_nearest_quotient_corrects_an_inexact_divide(ulps):
+    """The encode kernel's quotient is numpy's correctly rounded float32
+    divide even when the hardware divide it starts from is a few ulps
+    off (the TPU's is): simulated here by nudging the exact quotient."""
+    rng = np.random.default_rng(3)
+    s = (np.abs(rng.standard_normal(4096)) * 0.05 + 1e-3).astype(np.float32)
+    # operands whose quotients sit near the half-integers a code rounds at
+    k = rng.integers(-127, 127, 4096).astype(np.float32) + np.float32(0.5)
+    z = np.concatenate([(k * s).astype(np.float32),
+                        _rand(4096, seed=4), np.zeros(8, np.float32)])
+    s = np.concatenate([s, s, s[:8]])
+    exact = z / s
+    y = np.where(exact != 0, exact.view(np.int32) + np.int32(ulps), 0)
+    got = np.asarray(compress._nearest_quotient(
+        jnp.asarray(z), jnp.asarray(s), jnp.asarray(y.view(np.float32))))
+    np.testing.assert_array_equal(got.view(np.int32), exact.view(np.int32))
+
+
 @pytest.mark.parametrize("level", [1, 2])
 def test_roundtrip_error_bounded_by_quantization_step(level):
     x = _rand((16, 640), seed=7)

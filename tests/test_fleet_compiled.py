@@ -2,7 +2,7 @@
 
 `repro.fleet.compiled.CompiledFleetSimulator` runs the whole window
 pipeline -- gate -> per-device FIFO edge queues -> per-cell uplink ->
-shared cloud tier -- as ONE jitted JAX program (max-plus
+shared cloud tier -- as jitted JAX stages (max-plus
 `associative_scan` recurrences, `shard_map` over the cell axis). The
 host numpy `FleetSimulator` is the spec: these tests pin per-request
 parity to float round-off on `reference_fleet`, identical churn
@@ -177,6 +177,29 @@ def test_compiled_mesh_must_divide_cells(drift_data, scenario):
     sim = CompiledFleetSimulator(table, scenario.topology, L.paper_2020(),
                                  config=FleetConfig(window_s=0.5),
                                  mesh=FakeMesh())
+    with pytest.raises(ValueError, match="shard evenly"):
+        sim._resolve_mesh(scenario.topology.n_cells)
+
+
+def test_compiled_auto_mesh_never_falls_back_to_one_device(
+        drift_data, scenario, monkeypatch):
+    """mesh="auto" with several devices that do not divide the cell count
+    raises instead of quietly running on one device."""
+    import jax
+    import repro.sharding
+    from repro.fleet.compiled import CompiledFleetSimulator
+    from repro.fleet.simulator import FleetConfig
+
+    class FakeMesh:  # 4 devices over 6 cells
+        size = 4
+
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    monkeypatch.setattr(repro.sharding, "fleet_mesh", lambda: FakeMesh())
+    val, test, (uncal, global_plan, bank) = drift_data
+    table = fleet_gate_table(bank, scenario, backend="compiled")
+    sim = CompiledFleetSimulator(table, scenario.topology, L.paper_2020(),
+                                 config=FleetConfig(window_s=0.5))
+    assert sim.mesh == "auto"
     with pytest.raises(ValueError, match="shard evenly"):
         sim._resolve_mesh(scenario.topology.n_cells)
 
