@@ -15,6 +15,12 @@ always pairs branch-k logits with branch-k calibration. The engine keeps
 running statistics (offload rate, per-tier latency estimates) and works
 for the convnet (per-image classification, the paper's case) and for the
 LM families (per-sequence classification at prefill).
+
+Every engine call is cut into phases (edge, gate, gate sync, gather,
+codec, cloud, fetch) by one host-clock stamp at each boundary, so the
+phases tile the call: each phase's time accumulates in an EngineStats
+field, and the phase runs inside a profiler span `offload.<phase>`
+(docs/observability.md, "Tracing the served engine").
 """
 from __future__ import annotations
 
@@ -24,6 +30,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.policy import OffloadPlan
 
@@ -38,10 +45,72 @@ class EngineStats:
     cloud_calls: int = 0
     edge_time_s: float = 0.0  # wall-clock in edge_fn (blocked on device)
     cloud_time_s: float = 0.0  # wall-clock in cloud_fn
+    # the part of edge_time_s until edge_fn returns: the host's dispatch
+    edge_dispatch_s: float = 0.0
+    # infer's host phases; with edge_time_s and cloud_time_s they tile it
+    gate_time_s: float = 0.0  # plan.gate: exit-gate kernel and mask ops
+    gate_sync_time_s: float = 0.0  # mask, prediction, confidence to the host
+    gather_time_s: float = 0.0  # refused rows and their payload gather
+    codec_time_s: float = 0.0  # compress.encode + decode, (un)flatten
+    fetch_time_s: float = 0.0  # cloud logits to the host, softmax, scatter
 
     @property
     def offload_rate(self):
         return self.offloaded / max(self.requests, 1)
+
+
+#: each phase of an engine call: the EngineStats fields its time adds to,
+#: and the phase whose span encloses its own
+_PHASES = {
+    "edge": (("edge_time_s", "edge_dispatch_s"), None),
+    "edge_wait": (("edge_time_s",), "edge"),
+    "gate": (("gate_time_s",), None),
+    "gate_sync": (("gate_sync_time_s",), None),
+    "gather": (("gather_time_s",), None),
+    "encode": (("codec_time_s",), None),
+    "decode": (("codec_time_s",), None),
+    "cloud": (("cloud_time_s",), None),
+    "cloud_wait": (("cloud_time_s",), "cloud"),
+    "fetch": (("fetch_time_s",), None),
+}
+
+
+class _Phases:
+    """The phase clock of one engine call, a context manager.
+
+    `to(phase)` takes one `perf_counter` stamp: the running phase ends and
+    `phase` begins there, so consecutive phases tile the call from the
+    first stamp to the last, which leaving the context takes. A phase's
+    time is added to its EngineStats fields, and it runs inside the
+    profiler span `offload.<phase>`, which records nothing without a
+    profiler session.
+    """
+
+    def __init__(self, stats: EngineStats):
+        self.stats = stats
+        self.phase: Optional[str] = None
+        self.t = 0.0
+        self.spans: list = []  # (phase, open annotation), innermost last
+
+    def to(self, phase: Optional[str]) -> None:
+        t = time.perf_counter()
+        if self.phase is not None:
+            for f in _PHASES[self.phase][0]:
+                setattr(self.stats, f, getattr(self.stats, f) + (t - self.t))
+        parent = _PHASES[phase][1] if phase is not None else None
+        while self.spans and self.spans[-1][0] != parent:
+            self.spans.pop()[1].__exit__(None, None, None)
+        if phase is not None:
+            span = TraceAnnotation(f"offload.{phase}")
+            span.__enter__()
+            self.spans.append((phase, span))
+        self.t, self.phase = t, phase
+
+    def __enter__(self) -> "_Phases":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.to(None)
 
 
 class OffloadEngine:
@@ -59,8 +128,7 @@ class OffloadEngine:
     event-driven runtime (repro.serving.runtime) calls `edge_step` and
     `cloud_step` separately so queueing and transfer sit between them on
     the simulated clock. Both steps block until the device is done and
-    accumulate wall-clock in EngineStats; `timing_hook(tier, seconds,
-    batch_size)` observes every call (tier is "edge" or "cloud").
+    accumulate wall-clock in EngineStats.
     """
 
     def __init__(
@@ -71,7 +139,6 @@ class OffloadEngine:
         payload_nbytes: Optional[Callable[[Any], int]] = None,
         branch: Optional[int] = None,
         use_kernel: bool = False,
-        timing_hook: Optional[Callable[[str, float, int], None]] = None,
     ):
         self.edge_fn = edge_fn
         self.cloud_fn = cloud_fn
@@ -86,7 +153,6 @@ class OffloadEngine:
         self.payload_nbytes = payload_nbytes or (
             lambda p: sum(x.nbytes for x in jax.tree.leaves(p))
         )
-        self.timing_hook = timing_hook
         self.stats = EngineStats()
 
     @property
@@ -96,66 +162,76 @@ class OffloadEngine:
     # ------------------------------------------------------- timed steps
     def edge_step(self, batch) -> Dict[str, Any]:
         """Run the edge partition on one request batch (timed, blocking)."""
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(self.edge_fn(batch))
-        dt = time.perf_counter() - t0
-        b = int(out["exit_logits"].shape[0])
-        self.stats.edge_calls += 1
-        self.stats.edge_time_s += dt
-        if self.timing_hook is not None:
-            self.timing_hook("edge", dt, b)
-        return out
+        with _Phases(self.stats) as clock:
+            return self._edge(batch, clock)
 
     def cloud_step(self, payload) -> Dict[str, Any]:
         """Run the cloud partition on a refused-sample payload (timed)."""
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(self.cloud_fn(payload))
-        dt = time.perf_counter() - t0
-        m = int(out["logits"].shape[0])
+        with _Phases(self.stats) as clock:
+            return self._cloud(payload, clock)
+
+    def _edge(self, batch, clock: _Phases) -> Dict[str, Any]:
+        clock.to("edge")
+        out = self.edge_fn(batch)
+        clock.to("edge_wait")
+        out = jax.block_until_ready(out)
+        self.stats.edge_calls += 1
+        return out
+
+    def _cloud(self, payload, clock: _Phases) -> Dict[str, Any]:
+        clock.to("cloud")
+        out = self.cloud_fn(payload)
+        clock.to("cloud_wait")
+        out = jax.block_until_ready(out)
         self.stats.cloud_calls += 1
-        self.stats.cloud_time_s += dt
-        if self.timing_hook is not None:
-            self.timing_hook("cloud", dt, m)
         return out
 
     def infer(self, batch) -> Dict[str, np.ndarray]:
-        edge_out = self.edge_step(batch)
-        exit_logits = edge_out["exit_logits"]
-        gate = self.plan.gate(exit_logits, branch=self.branch,
-                              use_kernel=self.use_kernel)
-        mask = np.asarray(gate.exit_mask)
-        pred = np.asarray(gate.prediction).copy()
-        conf = np.asarray(gate.confidence).copy()
-        b = mask.shape[0]
+        with TraceAnnotation("offload.infer", batch=self.stats.edge_calls) as span, \
+                _Phases(self.stats) as clock:
+            edge_out = self._edge(batch, clock)
+            clock.to("gate")
+            gate = self.plan.gate(edge_out["exit_logits"], branch=self.branch,
+                                  use_kernel=self.use_kernel)
+            clock.to("gate_sync")
+            mask = np.asarray(gate.exit_mask)
+            pred = np.asarray(gate.prediction).copy()
+            conf = np.asarray(gate.confidence).copy()
+            b = mask.shape[0]
+            on = int(mask.sum())
+            self.stats.requests += b
+            self.stats.on_device += on
+            span.set_metadata(rows=b, refused=b - on)
 
-        self.stats.requests += b
-        self.stats.on_device += int(mask.sum())
+            if on < b:
+                clock.to("gather")
+                idx = np.nonzero(~mask)[0]
+                payload = jax.tree.map(lambda x: x[idx], edge_out["payload"])
+                self.stats.offloaded += len(idx)
+                level = int(getattr(self.plan, "compression_level", 0))
+                if level != 0:
+                    # the plan priced this deployment at the codec's wire
+                    # bytes; ship the ACTUAL encoded payload (Pallas kernel,
+                    # interpret mode off-TPU) and charge its analytic size
+                    from repro.kernels import compress
 
-        if (~mask).any():
-            idx = np.nonzero(~mask)[0]
-            payload = jax.tree.map(lambda x: x[idx], edge_out["payload"])
-            self.stats.offloaded += len(idx)
-            level = int(getattr(self.plan, "compression_level", 0))
-            if level != 0:
-                # the plan priced this deployment at the codec's wire
-                # bytes; ship the ACTUAL encoded payload (Pallas kernel,
-                # interpret mode off-TPU) and charge its analytic size
-                from repro.kernels import compress
-
-                leaves, treedef = jax.tree.flatten(payload)
-                encs = [compress.encode(x, level) for x in leaves]
-                self.stats.payload_bytes += sum(e.nbytes for e in encs)
-                payload = jax.tree.unflatten(
-                    treedef, [compress.decode(e) for e in encs]
-                )
-            else:
-                self.stats.payload_bytes += self.payload_nbytes(payload)
-            cloud_out = self.cloud_step(payload)
-            cloud_logits = np.asarray(cloud_out["logits"])
-            pred[idx] = np.argmax(cloud_logits, axis=-1)
-            z = cloud_logits - cloud_logits.max(-1, keepdims=True)
-            p = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
-            conf[idx] = p.max(-1)
+                    clock.to("encode")
+                    leaves, treedef = jax.tree.flatten(payload)
+                    encs = [compress.encode(x, level) for x in leaves]
+                    self.stats.payload_bytes += sum(e.nbytes for e in encs)
+                    clock.to("decode")
+                    payload = jax.tree.unflatten(
+                        treedef, [compress.decode(e) for e in encs]
+                    )
+                else:
+                    self.stats.payload_bytes += self.payload_nbytes(payload)
+                cloud_out = self._cloud(payload, clock)
+                clock.to("fetch")
+                cloud_logits = np.asarray(cloud_out["logits"])
+                pred[idx] = np.argmax(cloud_logits, axis=-1)
+                z = cloud_logits - cloud_logits.max(-1, keepdims=True)
+                p = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
+                conf[idx] = p.max(-1)
         return {
             "prediction": pred,
             "confidence": conf,

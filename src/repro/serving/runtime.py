@@ -120,8 +120,8 @@ class EngineCore:
     engines: {physical_branch: OffloadEngine} (one per deployable branch;
     a single-entry dict serves the paper's fixed-branch case). `data` is
     the batch pytree of the full dataset; requests index into its leading
-    axis. Uses the engines' edge_step/cloud_step so their timing hooks and
-    EngineStats keep working under the simulated clock.
+    axis. Uses the engines' edge_step/cloud_step so their EngineStats
+    counters and profiler spans keep working under the simulated clock.
     """
 
     def __init__(

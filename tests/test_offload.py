@@ -135,27 +135,104 @@ def test_simulator_network_repricing():
         )
 
 
-def test_engine_timing_hooks():
-    """edge_step/cloud_step accumulate wall-clock and fire the hook."""
-    from repro.core.policy import OffloadPlan
+def _stub_engine(exit_logits, level=0):
+    """An OffloadEngine over host stubs: 4 rows, 256-wide payloads."""
     from repro.core.calibration import TemperatureScaling
+    from repro.core.policy import OffloadPlan
     from repro.offload.engine import OffloadEngine
 
-    calls = []
-    engine = OffloadEngine(
-        edge_fn=lambda b: {"exit_logits": np.zeros((4, 10), np.float32),
-                           "payload": np.zeros((4, 8), np.float32)},
+    plan = OffloadPlan(p_tar=0.5,
+                       calibrators=[TemperatureScaling.from_temperature(1.0)])
+    return OffloadEngine(
+        edge_fn=lambda b: {"exit_logits": exit_logits,
+                           "payload": np.ones((4, 256), np.float32)},
         cloud_fn=lambda p: {"logits": np.ones((p.shape[0], 10), np.float32)},
-        plan=OffloadPlan(p_tar=0.5,
-                         calibrators=[TemperatureScaling.from_temperature(1.0)]),
-        timing_hook=lambda tier, dt, b: calls.append((tier, b)),
+        plan=plan.with_compression(level),
     )
+
+
+#: the EngineStats fields that tile an `infer` call
+TILING = ("edge_time_s", "cloud_time_s", "gate_time_s", "gate_sync_time_s",
+          "gather_time_s", "codec_time_s", "fetch_time_s")
+
+
+@pytest.mark.parametrize("level", [0, 1])
+def test_engine_timing_hooks(monkeypatch, level):
+    """The phase counters tile `infer`: with the engine's clock stubbed to
+    a fixed sequence, the seven tiling fields sum exactly to the time from
+    the call's first stamp to its last, each phase gets its own interval,
+    and a batch with no refused row adds nothing to the offload phases."""
+    import types
+
+    from repro.offload import engine as engine_mod
+
+    stamps = []
+
+    def clock():  # 0, 1, 3, 6, 10, ...: phase k lasts k + 1
+        stamps.append(stamps[-1] + len(stamps) if stamps else 0.0)
+        return stamps[-1]
+
+    monkeypatch.setattr(engine_mod, "time", types.SimpleNamespace(perf_counter=clock))
+    # uniform logits: every row is refused
+    engine = _stub_engine(np.zeros((4, 10), np.float32), level)
     out = engine.infer({"x": None})
+    st = engine.stats
     assert out["prediction"].shape == (4,)
-    assert engine.stats.edge_calls == 1
-    assert engine.stats.cloud_calls == 1  # uniform logits: all offloaded
-    assert engine.stats.edge_time_s > 0 and engine.stats.cloud_time_s > 0
-    assert ("edge", 4) in calls and ("cloud", 4) in calls
+    assert st.edge_calls == 1 and st.cloud_calls == 1 and st.offloaded == 4
+    assert sum(getattr(st, f) for f in TILING) == stamps[-1] - stamps[0]
+    # edge: dispatch 1 + wait 2; gate 3; gate sync 4; gather 5; then
+    # encode 6 + decode 7 where the plan has a codec; cloud 2 phases; fetch
+    codec = 6 + 7 if level else 0
+    cloud = 8 + 9 if level else 6 + 7
+    assert (st.edge_dispatch_s, st.edge_time_s) == (1, 3)
+    assert (st.gate_time_s, st.gate_sync_time_s, st.gather_time_s) == (3, 4, 5)
+    assert st.codec_time_s == codec
+    assert st.cloud_time_s == cloud
+    assert st.fetch_time_s == (10 if level else 8)
+
+    # confident logits: every row exits on the edge
+    stamps.clear()
+    confident = np.zeros((4, 10), np.float32)
+    confident[:, 3] = 20.0
+    engine = _stub_engine(confident, level)
+    engine.infer({"x": None})
+    st = engine.stats
+    assert st.cloud_calls == 0 and st.offloaded == 0
+    assert st.gather_time_s == st.codec_time_s == st.fetch_time_s == 0.0
+    assert sum(getattr(st, f) for f in TILING) == stamps[-1] - stamps[0]
+    assert st.edge_dispatch_s <= st.edge_time_s
+
+
+def test_engine_spans_land_in_a_profiler_trace(tmp_path):
+    """Under a profiler session with the host tracer on, every phase of
+    `infer` is an `offload.*` span of the host plane, inside `offload.infer`,
+    which carries the batch's sequence number, rows and refused rows."""
+    from jax.profiler import ProfileData, ProfileOptions
+
+    engine = _stub_engine(np.zeros((4, 10), np.float32), level=1)
+    engine.infer({"x": None})  # compile outside the trace
+    opts = ProfileOptions()
+    opts.host_tracer_level = 1
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        engine.infer({"x": None})
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("offload.")]
+    (parent,) = [e for e in events if e[0] == "offload.infer"]
+    assert parent[3] == {"batch": 1, "rows": 4, "refused": 4}
+    names = {"offload.edge", "offload.edge_wait", "offload.gate", "offload.gate_sync",
+             "offload.gather", "offload.encode", "offload.decode", "offload.cloud",
+             "offload.cloud_wait", "offload.fetch"}
+    children = [e for e in events if e[0] != "offload.infer"]
+    assert {e[0] for e in children} == names
+    assert all(parent[1] <= e[1] <= e[2] <= parent[2] for e in children)
+    inside = {"offload.edge_wait": "offload.edge", "offload.cloud_wait": "offload.cloud"}
+    for child, outer in inside.items():
+        (c,), (o,) = ([e for e in children if e[0] == n] for n in (child, outer))
+        assert o[1] <= c[1] <= c[2] <= o[2]
 
 
 def test_missed_deadline_monotone_in_t_tar():
