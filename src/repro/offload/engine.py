@@ -20,10 +20,13 @@ Every engine call is cut into phases (edge, gate, gate sync, gather,
 codec, cloud, fetch) by one host-clock stamp at each boundary, so the
 phases tile the call: each phase's time accumulates in an EngineStats
 field, and the phase runs inside a profiler span `offload.<phase>`
-(docs/observability.md, "Tracing the served engine").
+(docs/observability.md, "Tracing the served engine"). The gate and the
+gather are one compiled program each (`_exit_gate_step`, `_take_rows`),
+and the gate's three outputs reach the host in one overlapped fetch.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
@@ -53,6 +56,8 @@ class EngineStats:
     gather_time_s: float = 0.0  # refused rows and their payload gather
     codec_time_s: float = 0.0  # compress.encode + decode, (un)flatten
     fetch_time_s: float = 0.0  # cloud logits to the host, softmax, scatter
+    # device-to-host fetches: the gate's outputs, then any cloud logits
+    host_fetches: int = 0
 
     @property
     def offload_rate(self):
@@ -73,6 +78,25 @@ _PHASES = {
     "cloud_wait": (("cloud_time_s",), "cloud"),
     "fetch": (("fetch_time_s",), None),
 }
+
+
+@functools.partial(jax.jit, static_argnames=("criterion", "use_kernel"))
+def _exit_gate_step(exit_logits, state, p_tar, entropy_threshold, criterion,
+                    use_kernel):
+    """`OffloadPlan.gate` of one branch as one program: (exit_mask,
+    prediction, confidence). `p_tar`, `entropy_threshold` and the state's
+    arrays are traced, so a new threshold or refit compiles nothing; one
+    program per logits shape, calibrator kind and criterion."""
+    plan = OffloadPlan(p_tar=p_tar, calibrators=[state], criterion=criterion,
+                       entropy_threshold=entropy_threshold)
+    gate = plan.gate(exit_logits, branch=0, use_kernel=use_kernel)
+    return gate.exit_mask, gate.prediction, gate.confidence
+
+
+@jax.jit
+def _take_rows(payload, idx):
+    """Rows `idx` of every payload leaf (axis 0), one program per row count."""
+    return jax.tree.map(lambda x: x[idx], payload)
 
 
 class _Phases:
@@ -186,17 +210,28 @@ class OffloadEngine:
         self.stats.cloud_calls += 1
         return out
 
+    def _gate(self, exit_logits):
+        """(exit_mask, prediction, confidence) of the deployed branch: one
+        program where the plan gates as `OffloadPlan.gate` does, else the
+        plan's own gate (a subclass's or an instance's), called as it is."""
+        plan = self.plan
+        if getattr(plan.gate, "__func__", None) is not OffloadPlan.gate:
+            g = plan.gate(exit_logits, branch=self.branch, use_kernel=self.use_kernel)
+            return g.exit_mask, g.prediction, g.confidence
+        return _exit_gate_step(exit_logits, plan.calibrators[self.branch], plan.p_tar,
+                               plan.entropy_threshold, criterion=plan.criterion,
+                               use_kernel=self.use_kernel)
+
     def infer(self, batch) -> Dict[str, np.ndarray]:
         with TraceAnnotation("offload.infer", batch=self.stats.edge_calls) as span, \
                 _Phases(self.stats) as clock:
             edge_out = self._edge(batch, clock)
             clock.to("gate")
-            gate = self.plan.gate(edge_out["exit_logits"], branch=self.branch,
-                                  use_kernel=self.use_kernel)
+            gate = self._gate(edge_out["exit_logits"])
             clock.to("gate_sync")
-            mask = np.asarray(gate.exit_mask)
-            pred = np.asarray(gate.prediction).copy()
-            conf = np.asarray(gate.confidence).copy()
+            mask, pred, conf = jax.device_get(gate)
+            self.stats.host_fetches += 1
+            pred, conf = pred.copy(), conf.copy()
             b = mask.shape[0]
             on = int(mask.sum())
             self.stats.requests += b
@@ -205,8 +240,8 @@ class OffloadEngine:
 
             if on < b:
                 clock.to("gather")
-                idx = np.nonzero(~mask)[0]
-                payload = jax.tree.map(lambda x: x[idx], edge_out["payload"])
+                idx = np.flatnonzero(~mask).astype(np.int32)
+                payload = _take_rows(edge_out["payload"], idx)
                 self.stats.offloaded += len(idx)
                 level = int(getattr(self.plan, "compression_level", 0))
                 if level != 0:
@@ -228,6 +263,7 @@ class OffloadEngine:
                 cloud_out = self._cloud(payload, clock)
                 clock.to("fetch")
                 cloud_logits = np.asarray(cloud_out["logits"])
+                self.stats.host_fetches += 1
                 pred[idx] = np.argmax(cloud_logits, axis=-1)
                 z = cloud_logits - cloud_logits.max(-1, keepdims=True)
                 p = np.exp(z) / np.exp(z).sum(-1, keepdims=True)

@@ -235,6 +235,215 @@ def test_engine_spans_land_in_a_profiler_trace(tmp_path):
         assert o[1] <= c[1] <= c[2] <= o[2]
 
 
+def _plan(kind, criterion="confidence", p_tar=0.5, entropy_threshold=None):
+    """A two-exit plan whose branch 1 carries a `kind` calibrator."""
+    from repro.core.calibration import CalibratorState, TemperatureScaling
+    from repro.core.policy import OffloadPlan
+
+    deployed = {
+        "temperature": TemperatureScaling.from_temperature(1.7),
+        "vector": CalibratorState("vector", {
+            "w": jnp.linspace(0.5, 1.5, 10, dtype=jnp.float32),
+            "b": jnp.linspace(-0.3, 0.3, 10, dtype=jnp.float32)}),
+        "identity": CalibratorState("identity", {}),
+    }[kind]
+    return OffloadPlan(p_tar=p_tar, criterion=criterion,
+                       entropy_threshold=entropy_threshold,
+                       calibrators=[TemperatureScaling.from_temperature(9.0), deployed])
+
+
+def _eager_gate(plan, logits, use_kernel):
+    """`OffloadPlan.gate` of branch 1 called eagerly, as host arrays."""
+    g = plan.gate(jnp.asarray(logits), branch=1, use_kernel=use_kernel)
+    return tuple(np.asarray(a) for a in (g.exit_mask, g.prediction, g.confidence,
+                                         g.entropy))
+
+
+def _recording_engine(plan, logits, payload, use_kernel):
+    """An engine over branch 1 whose cloud partition keeps what it is sent."""
+    from repro.offload.engine import OffloadEngine
+
+    sent = []
+
+    def cloud(p):
+        sent.append(p)
+        return {"logits": jnp.zeros((jax.tree.leaves(p)[0].shape[0], 10))}
+
+    edge_out = {"exit_logits": jnp.asarray(logits),
+                "payload": jax.tree.map(jnp.asarray, payload)}
+    engine = OffloadEngine(lambda b: edge_out, cloud, plan, branch=1,
+                           use_kernel=use_kernel)
+    return engine, sent
+
+
+def _logits(rows=64, seed=7):
+    return (np.random.default_rng(seed).normal(size=(rows, 10)) * 2.5).astype(np.float32)
+
+
+#: (calibrator, criterion, use_kernel, payload) of each parity case
+PARITY = {
+    "kernel-temperature-confidence": ("temperature", "confidence", True, "image"),
+    "kernel-temperature-entropy": ("temperature", "entropy", True, "image"),
+    "kernel-vector-confidence": ("vector", "confidence", True, "image"),
+    "kernel-identity-confidence": ("identity", "confidence", True, "lm"),
+    "jnp-temperature-confidence": ("temperature", "confidence", False, "image"),
+    "jnp-vector-entropy": ("vector", "entropy", False, "image"),
+    "jnp-temperature-lm": ("temperature", "confidence", False, "lm"),
+}
+
+
+@pytest.mark.parametrize("case", list(PARITY))
+def test_engine_gate_and_gather_match_the_eager_path(case):
+    """`infer` answers as eager `plan.gate` followed by an eager `x[idx]`:
+    the same mask, predictions and edge confidences, and the cloud is sent
+    exactly the eagerly gathered rows of every payload leaf. Confidences
+    are bit for bit where the kernel reads the raw logits (a temperature or
+    identity calibrator); where XLA computes them, or the calibrated logits
+    the kernel reads, one program may round differently from op-by-op
+    dispatch (a fused multiply-add), within 2 ulp."""
+    kind, criterion, use_kernel, shape = PARITY[case]
+    logits = _logits()
+    rows = len(logits)
+    rng = np.random.default_rng(3)
+    payload = ({"h": rng.normal(size=(rows, 4, 4, 8)).astype(np.float32)}
+               if shape == "image" else
+               {"hidden": rng.normal(size=(rows, 6, 16)).astype(np.float32),
+                "pos": np.tile(np.arange(6, dtype=np.int32), (rows, 1))})
+    _, _, conf, ent = _eager_gate(_plan(kind), logits, use_kernel)
+    plan = _plan(kind, criterion, p_tar=float(np.quantile(conf, 0.4)),
+                 entropy_threshold=float(np.quantile(ent, 0.6)))
+    mask, pred, conf, _ = _eager_gate(plan, logits, use_kernel)
+    assert 0 < mask.sum() < rows
+
+    engine, sent = _recording_engine(plan, logits, payload, use_kernel)
+    out = engine.infer({})
+    np.testing.assert_array_equal(out["on_device"], mask)
+    np.testing.assert_array_equal(out["prediction"][mask], pred[mask])
+    if use_kernel and kind != "vector":
+        np.testing.assert_array_equal(out["confidence"][mask].view(np.uint32),
+                                      conf[mask].view(np.uint32))
+    else:
+        np.testing.assert_array_max_ulp(out["confidence"][mask], conf[mask], maxulp=2)
+    idx = np.nonzero(~mask)[0]
+    (got,) = sent
+    want = jax.tree.map(lambda x: np.asarray(jnp.asarray(x)[idx]), payload)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint32), w.view(np.uint32))
+
+
+@pytest.mark.parametrize("step", [0, 1, -1])
+def test_engine_gate_compares_in_float32_at_a_rows_confidence(step):
+    """With `p_tar` at a row's float32 confidence, or at either float64
+    neighbour of it, the compiled gate decides as the eager one: the
+    threshold is compared in float32, so the row exits in all three."""
+    logits = _logits()
+    _, _, conf, _ = _eager_gate(_plan("temperature"), logits, use_kernel=True)
+    row = int(np.argsort(conf)[len(conf) // 2])
+    at = float(conf[row])
+    p_tar = at if step == 0 else float(np.nextafter(at, step * np.inf))
+    assert (p_tar == at) is (step == 0)
+    plan = _plan("temperature", p_tar=p_tar)
+    mask, pred, conf, _ = _eager_gate(plan, logits, use_kernel=True)
+    engine, _ = _recording_engine(plan, logits, np.zeros((len(logits), 8), np.float32),
+                                  use_kernel=True)
+    out = engine.infer({})
+    assert mask[row] and out["on_device"][row]
+    np.testing.assert_array_equal(out["on_device"], mask)
+    np.testing.assert_array_equal(out["prediction"][mask], pred[mask])
+    np.testing.assert_array_equal(out["confidence"][mask].view(np.uint32),
+                                  conf[mask].view(np.uint32))
+
+
+def test_engine_counts_host_fetches():
+    """One fetch brings the gate's three outputs to the host; a batch with
+    refused rows makes one more, for the cloud's logits."""
+    refused = _stub_engine(np.zeros((4, 10), np.float32))
+    confident = np.zeros((4, 10), np.float32)
+    confident[:, 3] = 20.0
+    on_device = _stub_engine(confident)
+    for calls in (1, 2):
+        refused.infer({})
+        on_device.infer({})
+        assert refused.stats.host_fetches == 2 * calls
+        assert on_device.stats.host_fetches == calls
+
+
+class _Compiles:
+    """The names of the programs compiled inside the block (JAX's
+    monitoring events, as the chip benchmark's CompileCounter counts)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __enter__(self):
+        import jax.monitoring
+
+        self.names = []
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self.names
+
+    def _on(self, event, duration, fun_name="", **_):
+        if event == self.EVENT:
+            self.names.append(fun_name)
+
+    def __exit__(self, *exc):
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_duration_listener(self._on)
+
+
+def test_engine_compiles_nothing_again_for_a_count_or_a_threshold():
+    """A second call at the same refused count compiles no program, and a
+    new threshold (`with_p_tar`) moves the gate without a new gate program."""
+    logits = _logits(rows=16, seed=11)
+    plan = _plan("temperature")
+    _, _, conf, _ = _eager_gate(plan, logits, use_kernel=True)
+    plan = plan.with_p_tar(float(np.quantile(conf, 0.3)))
+    engine, _ = _recording_engine(plan, logits, np.ones((16, 8), np.float32),
+                                  use_kernel=True)
+    first = engine.infer({})["on_device"]
+    with _Compiles() as names:
+        again = engine.infer({})["on_device"]
+    assert names == []
+    np.testing.assert_array_equal(again, first)
+    engine.plan = engine.plan.with_p_tar(float(np.quantile(conf, 0.7)))
+    with _Compiles() as names:
+        moved = engine.infer({})["on_device"]
+    assert moved.sum() < first.sum()
+    assert not [n for n in names if "exit_gate" in n], names
+
+
+def test_engine_gate_program_is_the_one_the_gate_roofline_reads():
+    """The chip benchmark times the gate by the program name its
+    `exit_gate_roofline` reader matches and the codec by `codec_roofline`'s:
+    the engine's gate program matches the first alone, and its row gather
+    neither."""
+    import re
+    from pathlib import Path
+
+    from repro.offload.engine import _exit_gate_step, _take_rows
+
+    metrics = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / "metrics"
+
+    def pattern(metric):
+        text = (metrics / f"{metric}.py").read_text()
+        return re.search(r'^PROGRAM = r"(.+)"$', text, re.M).group(1)
+
+    def module(lowered):
+        return re.match(r"HloModule (\S+?),", lowered.compile().as_text()).group(1)
+
+    gate_rx, codec_rx = pattern("exit_gate_roofline"), pattern("codec_roofline")
+    plan = _plan("temperature")
+    gate = module(_exit_gate_step.lower(
+        jnp.zeros((256, 10)), plan.calibrators[1], plan.p_tar, None,
+        criterion="confidence", use_kernel=True))
+    take = module(_take_rows.lower(jnp.zeros((256, 16, 16, 64)),
+                                   np.arange(26, dtype=np.int32)))
+    assert re.search(gate_rx, gate) and not re.search(codec_rx, gate), gate
+    assert not re.search(gate_rx, take) and not re.search(codec_rx, take), take
+
+
 def test_missed_deadline_monotone_in_t_tar():
     n, c = 2048, 10
     rng = np.random.default_rng(0)
