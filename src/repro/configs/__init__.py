@@ -24,6 +24,7 @@ ARCHS = [
     "jamba_v01_52b",
     "whisper_base",
     "qwen2_72b",
+    "granite_4_0_h_micro",
     "b_alexnet",  # the paper's own architecture
 ]
 
@@ -41,6 +42,7 @@ _ALIASES.update(
         "jamba-v0.1-52b": "jamba_v01_52b",
         "whisper-base": "whisper_base",
         "qwen2-72b": "qwen2_72b",
+        "granite-4.0-h-micro": "granite_4_0_h_micro",
         "b-alexnet": "b_alexnet",
     }
 )

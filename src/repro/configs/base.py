@@ -34,8 +34,15 @@ class ModelConfig:
 
     # --- norm / mlp ---------------------------------------------------------
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm | nonparametric_ln
+    norm_eps: float = 1e-6  # every norm: blocks, heads, exits, Mamba's gate
     mlp_type: str = "swiglu"  # swiglu | gelu
     tie_embeddings: bool = False
+
+    # --- Granite's scalings (defaults: none) ----------------------------------
+    embedding_multiplier: float = 1.0  # token embeddings times this
+    attention_multiplier: Optional[float] = None  # score scale; None: 1/sqrt(hd)
+    residual_multiplier: float = 1.0  # each block branch times this before its add
+    logits_scaling: float = 1.0  # final and exit logits divided by this
 
     # --- MoE ----------------------------------------------------------------
     moe_num_experts: int = 0

@@ -229,22 +229,24 @@ def _padded_dims(rows: int, n_groups: int, block_rows: int = 8):
 
 @functools.partial(jax.jit, static_argnames=("bits",))
 def _encode_wire(z, bits: int):
-    """(rows, cols) float32 -> wire (words, scales) of `ref.encode_codec_ref`."""
+    """(rows, cols) float -> wire (words, scales) of `ref.encode_codec_ref`,
+    which encodes the payload's float32 values."""
     per = 32 // bits
     nw = CODEC_TILE // per
     rows, cols = z.shape
     ng = -(-cols // CODEC_TILE)
     rp, gp = _padded_dims(rows, ng)
-    z = jnp.pad(z, ((0, rp - rows), (0, gp * CODEC_TILE - cols)))
+    z = jnp.pad(z.astype(jnp.float32), ((0, rp - rows), (0, gp * CODEC_TILE - cols)))
     planes = z.reshape(rp, gp, nw, per).transpose(0, 3, 2, 1)
     words, scales = encode_pallas(planes, bits, interpret=interpret_mode())
     words = words.transpose(0, 2, 1)[:rows, :ng].reshape(rows, ng * nw)
     return words, scales[:rows, 0, :ng]
 
 
-@functools.partial(jax.jit, static_argnames=("bits",))
-def _decode_wire(words, scales, bits: int):
-    """Wire (words, scales) -> (rows, groups * 128) float32."""
+@functools.partial(jax.jit, static_argnames=("bits", "dtype"))
+def _decode_wire(words, scales, bits: int, dtype=jnp.float32):
+    """Wire (words, scales) -> (rows, groups * 128) float32 values, rounded
+    to `dtype`."""
     per = 32 // bits
     nw = CODEC_TILE // per
     rows, ng = scales.shape
@@ -254,7 +256,7 @@ def _decode_wire(words, scales, bits: int):
     planes = decode_pallas(w.transpose(0, 2, 1), sc, bits,
                            interpret=interpret_mode())
     out = planes.transpose(0, 3, 2, 1).reshape(rp, gp * CODEC_TILE)
-    return out[:rows, : ng * CODEC_TILE]
+    return out[:rows, : ng * CODEC_TILE].astype(dtype)
 
 
 # ----------------------------------------------------------- public wrappers
@@ -268,6 +270,7 @@ class EncodedPayload:
     scales: Any  # (rows, ceil(features/128)) float32
     shape: Tuple[int, ...]
     level: int
+    dtype: Any = jnp.float32  # the payload's dtype, which decode returns
 
     @property
     def nbytes(self) -> int:
@@ -284,20 +287,18 @@ def encode(x, level: int) -> EncodedPayload:
         raise ValueError("level 0 is the identity; nothing to encode")
     x = jnp.asarray(x)
     rows, cols = _codec_layout(x.shape)
-    words, scales = _encode_wire(
-        x.reshape(rows, cols).astype(jnp.float32), CODEC_BITS[level]
-    )
+    words, scales = _encode_wire(x.reshape(rows, cols), CODEC_BITS[level])
     return EncodedPayload(
         words=words, scales=scales,
-        shape=tuple(int(d) for d in x.shape), level=level,
+        shape=tuple(int(d) for d in x.shape), level=level, dtype=x.dtype,
     )
 
 
 def decode(enc: EncodedPayload):
-    """Decode an `EncodedPayload` back to float32 in its original shape."""
+    """Decode an `EncodedPayload` back to its original shape and dtype."""
     rows, cols = _codec_layout(enc.shape)
     out = _decode_wire(jnp.asarray(enc.words), jnp.asarray(enc.scales),
-                       CODEC_BITS[int(enc.level)])
+                       CODEC_BITS[int(enc.level)], jnp.dtype(enc.dtype))
     return out[:, :cols].reshape(enc.shape)
 
 

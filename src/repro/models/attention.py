@@ -51,8 +51,8 @@ def _project_qkv(p, cfg, x, positions, rope=True):
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     if cfg.qk_norm:
-        q = rms_norm_headwise(q, p["q_norm"])
-        k = rms_norm_headwise(k, p["k_norm"])
+        q = rms_norm_headwise(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm_headwise(k, p["k_norm"], cfg.norm_eps)
     if rope and cfg.use_rope:
         cos, sin = rope_freqs(cfg, positions)
         q = apply_rope(q, cos, sin)
@@ -60,14 +60,16 @@ def _project_qkv(p, cfg, x, positions, rope=True):
     return q, k, v
 
 
-def _gqa_scores(q, k):
-    """q: (b,sq,qh,hd) k: (b,sk,kvh,hd) -> (b,kvh,g,sq,sk) fp32."""
+def _gqa_scores(cfg, q, k):
+    """q: (b,sq,qh,hd) k: (b,sk,kvh,hd) -> (b,kvh,g,sq,sk) fp32, times the
+    score scale: `cfg.attention_multiplier`, else 1/sqrt(head_dim)."""
     b, sq, qh, hd = q.shape
     kvh = k.shape[2]
     g = qh // kvh
     qg = q.reshape(b, sq, kvh, g, hd)
     s = jnp.einsum("bqhgk,bshk->bhgqs", qg, k).astype(jnp.float32)
-    return s * (hd ** -0.5)
+    scale = cfg.attention_multiplier
+    return s * (hd ** -0.5 if scale is None else scale)
 
 
 def _gqa_out(probs, v):
@@ -91,7 +93,7 @@ def attention_prefill(p, cfg, x, positions, q_chunk=1024, memory=None):
             q = q + p["bq"]
         k = jnp.einsum("bsd,dhk->bshk", memory, p["wk"])
         v = jnp.einsum("bsd,dhk->bshk", memory, p["wv"])
-        scores = _gqa_scores(q, k)
+        scores = _gqa_scores(cfg, q, k)
         probs = jax.nn.softmax(scores, axis=-1)
         o = _gqa_out(probs, v)
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), {"k": k, "v": v}
@@ -119,7 +121,7 @@ def attention_prefill(p, cfg, x, positions, q_chunk=1024, memory=None):
 
 def _attend_block(cfg, q, k, v, q_pos, k_pos):
     """q: (b,sq,qh,hd); k/v: (b,sk,kvh,hd); positions (b,sq)/(b,sk)."""
-    scores = _gqa_scores(q, k)  # (b,kvh,g,sq,sk)
+    scores = _gqa_scores(cfg, q, k)  # (b,kvh,g,sq,sk)
     mask = q_pos[:, :, None] >= k_pos[:, None, :]  # causal (b,sq,sk)
     if cfg.sliding_window:
         mask &= (q_pos[:, :, None] - k_pos[:, None, :]) < cfg.sliding_window
@@ -148,7 +150,7 @@ def attention_decode_stacked(p, cfg, x, cache, pos, layer_idx):
     layer_k = jax.lax.dynamic_slice_in_dim(ck, layer_idx, 1, axis=0)[0]
     layer_v = jax.lax.dynamic_slice_in_dim(cv, layer_idx, 1, axis=0)[0]
 
-    scores = _gqa_scores(q, layer_k)
+    scores = _gqa_scores(cfg, q, layer_k)
     idx = jnp.arange(L)
     if cfg.sliding_window:
         age = (slot - idx) % L
@@ -184,7 +186,7 @@ def attention_decode(p, cfg, x, cache, pos, memory_cache=None):
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
         if cfg.qkv_bias:
             q = q + p["bq"]
-        scores = _gqa_scores(q, memory_cache["k"])
+        scores = _gqa_scores(cfg, q, memory_cache["k"])
         probs = jax.nn.softmax(scores, axis=-1)
         o = _gqa_out(probs, memory_cache["v"])
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), cache
@@ -197,7 +199,7 @@ def attention_decode(p, cfg, x, cache, pos, memory_cache=None):
     ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
     cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
 
-    scores = _gqa_scores(q, ck)  # (b,kvh,g,1,L)
+    scores = _gqa_scores(cfg, q, ck)  # (b,kvh,g,1,L)
     idx = jnp.arange(L)
     if cfg.sliding_window:
         # ring buffer: entry i holds absolute position p with p % L == i, the

@@ -27,7 +27,8 @@ def init_norm(key, cfg, d=None):
     return {"scale": jnp.ones((d,), jnp.float32)}
 
 
-def apply_norm(p, cfg, x, eps=1e-6):
+def apply_norm(p, cfg, x):
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm_type in ("layernorm", "nonparametric_ln"):
         mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -42,7 +43,7 @@ def apply_norm(p, cfg, x, eps=1e-6):
     return y.astype(x.dtype)
 
 
-def rms_norm_headwise(x, scale, eps=1e-6):
+def rms_norm_headwise(x, scale, eps):
     """qk-norm: RMS over the head_dim of (..., head_dim)."""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)

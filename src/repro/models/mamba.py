@@ -81,7 +81,7 @@ def _gated_out(p, cfg, y, z):
     # gated RMSNorm: norm(y * silu(z)) * scale
     yz = (y * jax.nn.silu(z.astype(jnp.float32))).astype(jnp.float32)
     ms = jnp.mean(jnp.square(yz), axis=-1, keepdims=True)
-    yn = yz * jax.lax.rsqrt(ms + 1e-6) * p["norm_scale"]
+    yn = yz * jax.lax.rsqrt(ms + cfg.norm_eps) * p["norm_scale"]
     return yn.astype(cdtype(cfg)) @ p["out_proj"]
 
 

@@ -11,6 +11,15 @@ scanned block -- essential for dry-run compile times -- while hybrid models
 Early exits (the paper's technique): after segment boundaries listed in
 cfg.exit_layers, an exit head (norm + unembed) produces side-branch logits.
 The stack returns them all; gating/calibration live in repro.core.
+
+Granite's scalings apply where its modelling code puts them, each a no-op
+at its default: token embeddings times `embedding_multiplier`, each
+mixer and MLP branch times `residual_multiplier` before its residual add,
+final and exit logits divided by `logits_scaling` (the score scale and the
+norms' eps live in attention.py, layers.py and mamba.py). Each block's
+mixer runs under `jax.named_scope("mamba")` or `("attention")` and its
+dense MLP under `("mlp")`, so device traces name the operations of each
+(docs/observability.md).
 """
 from __future__ import annotations
 
@@ -75,25 +84,38 @@ def init_block(key, cfg, kind):
     return p
 
 
+def _branch(cfg, h):
+    """A block branch as it joins the residual stream."""
+    m = cfg.residual_multiplier
+    return h if m == 1.0 else h * m
+
+
+def _apply_ffn(p, cfg, ffn, x):
+    """The block's feed-forward branch and its residual add: (x, aux)."""
+    if ffn == "none":
+        return x, {}
+    h = apply_norm(p["ffn_norm"], cfg, x)
+    if ffn == "dense":
+        with jax.named_scope("mlp"):
+            h, aux = apply_mlp(p["mlp"], cfg, h), {}
+    else:
+        from repro.models.moe import apply_moe
+
+        h, aux = apply_moe(p["moe"], cfg, h)
+    return x + _branch(cfg, h), aux
+
+
 def apply_block_seq(p, cfg, kind, x, positions):
     """Full-sequence (train/prefill) block. Returns (x, cache, aux)."""
     mixer, ffn = kind
     h = apply_norm(p["mixer_norm"], cfg, x)
     if mixer == "attn":
-        h, cache = attn.attention_prefill(p["attn"], cfg, h, positions)
+        with jax.named_scope("attention"):
+            h, cache = attn.attention_prefill(p["attn"], cfg, h, positions)
     else:
-        h, cache = mb.mamba_prefill(p["mamba"], cfg, h)
-    x = x + h
-    aux = {}
-    if ffn != "none":
-        h = apply_norm(p["ffn_norm"], cfg, x)
-        if ffn == "dense":
-            h = apply_mlp(p["mlp"], cfg, h)
-        else:
-            from repro.models.moe import apply_moe
-
-            h, aux = apply_moe(p["moe"], cfg, h)
-        x = x + h
+        with jax.named_scope("mamba"):
+            h, cache = mb.mamba_prefill(p["mamba"], cfg, h)
+    x, aux = _apply_ffn(p, cfg, ffn, x + _branch(cfg, h))
     x = sharding.constrain(x, "dp", None, None)
     return x, cache, aux
 
@@ -102,19 +124,12 @@ def apply_block_decode(p, cfg, kind, x, cache, pos):
     mixer, ffn = kind
     h = apply_norm(p["mixer_norm"], cfg, x)
     if mixer == "attn":
-        h, cache = attn.attention_decode(p["attn"], cfg, h, cache, pos)
+        with jax.named_scope("attention"):
+            h, cache = attn.attention_decode(p["attn"], cfg, h, cache, pos)
     else:
-        h, cache = mb.mamba_decode(p["mamba"], cfg, h, cache, pos)
-    x = x + h
-    if ffn != "none":
-        h = apply_norm(p["ffn_norm"], cfg, x)
-        if ffn == "dense":
-            h = apply_mlp(p["mlp"], cfg, h)
-        else:
-            from repro.models.moe import apply_moe
-
-            h, _ = apply_moe(p["moe"], cfg, h)
-        x = x + h
+        with jax.named_scope("mamba"):
+            h, cache = mb.mamba_decode(p["mamba"], cfg, h, cache, pos)
+    x, _ = _apply_ffn(p, cfg, ffn, x + _branch(cfg, h))
     x = sharding.constrain(x, "dp", None, None)
     return x, cache
 
@@ -124,11 +139,14 @@ def _apply_block_decode_stacked(p, cfg, kind, x, cache, pos, layer_idx):
     mixer, ffn = kind
     h = apply_norm(p["mixer_norm"], cfg, x)
     if mixer == "attn":
-        h, cache = attn.attention_decode_stacked(p["attn"], cfg, h, cache, pos, layer_idx)
+        with jax.named_scope("attention"):
+            h, cache = attn.attention_decode_stacked(p["attn"], cfg, h, cache, pos,
+                                                     layer_idx)
     else:
         # mamba state IS the full per-layer payload: slice, update, write back
         layer_c = jax.tree.map(lambda a: a[layer_idx], cache)
-        h, layer_c = mb.mamba_decode(p["mamba"], cfg, h, layer_c, pos)
+        with jax.named_scope("mamba"):
+            h, layer_c = mb.mamba_decode(p["mamba"], cfg, h, layer_c, pos)
         cache = jax.tree.map(
             lambda a, l: jax.lax.dynamic_update_slice_in_dim(
                 a, l[None].astype(a.dtype), layer_idx, axis=0
@@ -136,16 +154,7 @@ def _apply_block_decode_stacked(p, cfg, kind, x, cache, pos, layer_idx):
             cache,
             layer_c,
         )
-    x = x + h
-    if ffn != "none":
-        h = apply_norm(p["ffn_norm"], cfg, x)
-        if ffn == "dense":
-            h = apply_mlp(p["mlp"], cfg, h)
-        else:
-            from repro.models.moe import apply_moe
-
-            h, _ = apply_moe(p["moe"], cfg, h)
-        x = x + h
+    x, _ = _apply_ffn(p, cfg, ffn, x + _branch(cfg, h))
     x = sharding.constrain(x, "dp", None, None)
     return x, cache
 
@@ -187,20 +196,34 @@ def init_params(key, cfg: ModelConfig):
     return params
 
 
+def _embed(params, cfg, tokens):
+    """Token embeddings, times the embedding multiplier."""
+    x = apply_embed(params["embed"], tokens)
+    m = cfg.embedding_multiplier
+    return x if m == 1.0 else x * m
+
+
+def _scaled_logits(cfg, logits):
+    """Logits divided by the logits scaling, sharded over the vocabulary."""
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return sharding.constrain(logits, "dp", None, "tp")
+
+
 def _lm_logits(params, cfg, x):
     h = apply_norm(params["final_norm"], cfg, x)
     if cfg.tie_embeddings:
         logits = h @ params["embed"]["w"].T
     else:
         logits = apply_unembed(params["lm_head"], h)
-    return sharding.constrain(logits, "dp", None, "tp")
+    return _scaled_logits(cfg, logits)
 
 
 def exit_logits_fn(params, cfg, i, x):
+    """Exit head `i`: its own norm and unembed, scaled as the final head."""
     ep = params["exits"][i]
     h = apply_norm(ep["norm"], cfg, x)
-    logits = apply_unembed(ep["head"], h)
-    return sharding.constrain(logits, "dp", None, "tp")
+    return _scaled_logits(cfg, apply_unembed(ep["head"], h))
 
 
 def _run_segments_seq(params, cfg, x, positions, remat: bool):
@@ -239,7 +262,7 @@ def forward_train(params, cfg: ModelConfig, batch, remat: bool = True):
     """batch: {tokens (b, s) int32, ...}. Returns logits dict for the loss."""
     tokens = batch["tokens"]
     b, s = tokens.shape
-    x = apply_embed(params["embed"], tokens)
+    x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     if cfg.max_position_embeddings:
         x = x + params["pos_embed"][:s][None]
@@ -256,7 +279,7 @@ def forward_prefill(params, cfg: ModelConfig, batch):
     """Prefill: full sequence, returns last-position logits + caches + exits."""
     tokens = batch["tokens"]
     b, s = tokens.shape
-    x = apply_embed(params["embed"], tokens)
+    x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     if cfg.max_position_embeddings:
         x = x + params["pos_embed"][:s][None]
@@ -287,7 +310,7 @@ def decode_step(params, cfg: ModelConfig, token, caches, pos):
     out: {"logits": (b,1,V), "exit_logits": [(b,1,V)...]}
     """
     segs = segment_plan(cfg)
-    x = apply_embed(params["embed"], token)
+    x = _embed(params, cfg, token)
     if cfg.max_position_embeddings:
         x = x + params["pos_embed"][pos][None, None, :]
     x = sharding.constrain(x, "dp", None, None)
@@ -332,7 +355,7 @@ def edge_forward(params, cfg: ModelConfig, batch, exit_index: int = 0):
     """
     tokens = batch["tokens"]
     b, s = tokens.shape
-    x = apply_embed(params["embed"], tokens)
+    x = _embed(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     if cfg.max_position_embeddings:
         x = x + params["pos_embed"][:s][None]

@@ -297,19 +297,32 @@ def convnet_engine(params, plan: OffloadPlan, branch: int = 1,
     return OffloadEngine(edge, cloud, plan, branch=branch - 1, use_kernel=use_kernel)
 
 
-def lm_engine(params, cfg, plan: OffloadPlan, exit_index: int = 0,
-              use_kernel: bool = False) -> OffloadEngine:
-    """LM variant: classify-at-prefill; edge = blocks up to the exit."""
+@functools.partial(jax.jit, static_argnames=("cfg", "exit_index"))
+def _lm_edge(params, batch, cfg, exit_index):
+    """The LM's edge partition, weights as an argument: the exit's
+    last-position logits and the (b, s, d) hidden state it ships."""
     from repro.models import transformer
 
-    @jax.jit
-    def edge(batch):
-        out = transformer.edge_forward(params, cfg, batch, exit_index=exit_index)
-        return {"exit_logits": out["exit_logits"][:, 0, :], "payload": out["hidden"]}
+    out = transformer.edge_forward(params, cfg, batch, exit_index=exit_index)
+    return {"exit_logits": out["exit_logits"][:, 0, :], "payload": out["hidden"]}
 
-    @jax.jit
-    def cloud(hidden):
-        out = transformer.cloud_forward(params, cfg, hidden, exit_index=exit_index)
-        return {"logits": out["logits"][:, 0, :]}
 
+@functools.partial(jax.jit, static_argnames=("cfg", "exit_index"))
+def _lm_cloud(params, hidden, cfg, exit_index):
+    """The LM's cloud partition, weights as an argument: last-position logits."""
+    from repro.models import transformer
+
+    out = transformer.cloud_forward(params, cfg, hidden, exit_index=exit_index)
+    return {"logits": out["logits"][:, 0, :]}
+
+
+def lm_engine(params, cfg, plan: OffloadPlan, exit_index: int = 0,
+              use_kernel: bool = False) -> OffloadEngine:
+    """LM variant: classify-at-prefill; edge = blocks up to the exit.
+
+    Both partitions take the weights as an argument (bound here with
+    `functools.partial`), so no program embeds them as constants and one
+    compiled program serves any weights of the same configuration."""
+    edge = functools.partial(_lm_edge, params, cfg=cfg, exit_index=exit_index)
+    cloud = functools.partial(_lm_cloud, params, cfg=cfg, exit_index=exit_index)
     return OffloadEngine(edge, cloud, plan, branch=exit_index, use_kernel=use_kernel)
